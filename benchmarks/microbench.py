@@ -1,9 +1,11 @@
-"""Microbenchmarks: aggregation operators + Pallas kernels (interpret mode).
+"""Microbenchmarks: aggregation operators + the flat aggregation kernel.
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmark harness contract).
-On CPU these measure the *algorithmic* layers (operators, oracles); kernel
-rows run in interpret mode and are correctness-representative only — real
-kernel throughput requires a TPU.
+On CPU these measure the *algorithmic* layers (operators, oracles).  The
+kernel row goes through the hot path's dispatch
+(``kernels.ops.resolve_kernel_mode``): the compiled Mosaic kernel on a
+TPU, the fused jnp twin elsewhere — its ``derived`` column names which
+ran, and on which device.
 """
 from __future__ import annotations
 
@@ -22,10 +24,9 @@ from repro.core.operators import (
     owa_score,
     prioritized_score,
 )
+from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.kernels.weighted_agg import weighted_agg
-from repro.kernels.divergence import divergence_sq
-from repro.utils.pytree import tree_weighted_sum
+from repro.utils.compile_cache import use_compile_cache
 
 
 def bench(fn, *args, iters=50, warmup=5) -> float:
@@ -67,9 +68,13 @@ def main() -> None:
     us = bench(f_jnp, stacked, weights, iters=10)
     gbps = K * N * 4 / (us / 1e6) / 1e9
     rows.append(("agg_jnp_37x206k", us, f"{gbps:.1f}GB/s"))
-    us = bench(lambda s, w: weighted_agg(s, w, interpret=True),
-               stacked, weights, iters=3, warmup=1)
-    rows.append(("agg_pallas_interp_37x206k", us, "interpret-mode"))
+    use_pallas, _ = kops.resolve_kernel_mode()
+    f_kernel = jax.jit(lambda s, w: kops.flat_weighted_agg(s, w))
+    dev = jax.devices()[0]
+    rows.append(("agg_flat_dispatch_37x206k",
+                 bench(f_kernel, stacked, weights, iters=10),
+                 f"{'pallas' if use_pallas else 'jnp'} on "
+                 f"{dev.platform}/{dev.device_kind}"))
 
     f_div = jax.jit(lambda s, g: ref.divergence_ref(s, g))
     g = stacked[0]
@@ -91,4 +96,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

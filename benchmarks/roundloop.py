@@ -62,11 +62,11 @@ for the metric schema and sim-time units):
   slow on XLA CPU (see ``models/mlp.py``), so CNN-scale server numbers
   come from the MLP like every other engine benchmark.
 * **Scale** — the mesh-parallel server round block over fleet size x
-  shard count (K up to 10^6 clients, client axis forced onto 8 host
-  devices): rounds/sec plus the per-shard byte footprint of the O(K)
-  server state and the ``[S, N]`` wave block.  Each grid point runs in
-  a subprocess so ``XLA_FLAGS=--xla_force_host_platform_device_count``
-  can be set before jax imports (see :func:`bench_scale`).
+  shard count (K up to 10^6 clients): rounds/sec plus the per-shard
+  byte footprint of the O(K) server state and the ``[S, N]`` wave
+  block.  On an accelerator every point runs in this process over the
+  host's own devices; on the CPU the client axis is forced onto 8 host
+  devices, one subprocess per point (see :func:`bench_scale`).
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmark harness
 contract); :func:`main` also returns the results as a dict, which
@@ -110,6 +110,7 @@ from repro.federated.simulation import FederatedSimulation, FedSimConfig
 from repro.kernels import ops as kops
 from repro.models.mlp import init_mlp_params, mlp_accuracy, mlp_loss
 from repro.optim.optimizers import sgd
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.pytree import FlatSpec, tree_count_params, tree_weighted_sum
 
 #: the selection sweep grid — every policy under both aggregation modes
@@ -775,16 +776,17 @@ def _scale_worker(cfg: dict) -> dict:
     from repro.federated.engine import RoundInputs, ServerState, SyncStrategy
     from repro.kernels import collective as kcoll
     from repro.launch.mesh import client_sharding, make_host_mesh
-    from repro.utils.sharding import shard_map_compat
 
     K, S, N = cfg["K"], cfg["S"], cfg["N"]
     rounds, repeats, shards = cfg["rounds"], cfg["repeats"], cfg["shards"]
     if len(jax.devices()) < shards:
         raise RuntimeError(
-            f"need {shards} devices, have {len(jax.devices())}; the parent "
-            "must set XLA_FLAGS=--xla_force_host_platform_device_count")
+            f"need {shards} devices, have {len(jax.devices())}; on the CPU "
+            "the parent must set XLA_FLAGS=--xla_force_host_platform_"
+            "device_count")
 
-    mesh = make_host_mesh() if shards > 1 else None
+    mesh = (make_host_mesh(devices=jax.devices()[:shards]) if shards > 1
+            else None)
     shard = client_sharding(mesh) if mesh is not None else None
     n_sh = shard.num_shards if shard is not None else 1
     s_loc = S // n_sh
@@ -831,8 +833,8 @@ def _scale_worker(cfg: dict) -> dict:
             params=P(), quality=P(), priority_idx=P(), last_sync=k_spec,
             sim_time=P(), commits=P(), buffer=P(), buffer_weight=P(),
             buffer_count=P(), in_buffer=k_spec)
-        block = shard_map_compat(block, mesh, in_specs=(state_spec, P()),
-                                 out_specs=(state_spec, P()))
+        block = jax.shard_map(block, mesh=mesh, in_specs=(state_spec, P()),
+                              out_specs=(state_spec, P()), check_vma=False)
 
     fn = jax.jit(block)
     ids = jnp.arange(1, rounds + 1, dtype=jnp.int32)
@@ -847,8 +849,10 @@ def _scale_worker(cfg: dict) -> dict:
 
     state_bytes = int(sum(l.nbytes for l in jax.tree.leaves(state)))
     sharded_bytes = int(state.last_sync.nbytes)   # [K] fields live split
+    dev = jax.devices()[0]
     return {
         "K": K, "S": S, "num_params": N, "shards": n_sh, "rounds": rounds,
+        "platform": dev.platform, "device_kind": dev.device_kind,
         "rounds_per_sec": best,
         "server_state_bytes_global": state_bytes,
         "server_state_bytes_per_shard":
@@ -863,55 +867,64 @@ def _scale_worker(cfg: dict) -> dict:
 def bench_scale(smoke: bool = False) -> dict:
     """Mesh-parallel server round block over K x shard-count (``scale``).
 
-    Every grid point runs in a fresh subprocess: the forced host device
-    count is baked into ``XLA_FLAGS`` *before* jax imports, so 1-shard
-    and 8-shard points can share one parent process.  Throughput numbers
-    on a forced-CPU mesh measure dispatch + collective overhead, not
+    On an accelerator every grid point runs in this process, the sharded
+    points over ``jax.devices()[:shards]`` (up to 8 of the host's
+    devices): the process that touched JAX holds the chips, so no child
+    could use them.  On the CPU the sweep is a rehearsal: each point
+    runs in a fresh subprocess whose forced host device count is baked
+    into ``XLA_FLAGS`` *before* jax imports.  Throughput on a
+    forced-CPU mesh measures dispatch + collective overhead, not
     parallel speedup (the "devices" share the host's cores); the
-    per-shard byte columns are the headline — they show the O(K) state
-    and the ``[S, N]`` wave splitting across the client axis.
+    per-shard byte columns show the O(K) state and the ``[S, N]`` wave
+    splitting across the client axis.
     """
+    on_cpu = jax.default_backend() == "cpu"
+    wide = 8 if on_cpu else min(8, len(jax.devices()))
+    shard_counts = (1, wide) if wide > 1 else (1,)
     if smoke:
         grid = [dict(K=1_000, S=64, N=4_096, rounds=4, repeats=1, shards=sh)
-                for sh in (1, 8)]
+                for sh in shard_counts]
     else:
         grid = []
         for K in (1_000, 10_000, 100_000, 1_000_000):
             S = 512 if K == 1_000 else 1024
             rounds = 4 if K <= 10_000 else 2
-            for sh in (1, 8):
+            for sh in shard_counts:
                 grid.append(dict(K=K, S=S, N=131_072, rounds=rounds,
                                  repeats=1, shards=sh))
-    src = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    records = []
-    for cfg in grid:
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORM_NAME", "cpu")
-        if cfg["shards"] > 1:
-            env["XLA_FLAGS"] = (
-                f"--xla_force_host_platform_device_count={cfg['shards']}")
-        else:
-            env.pop("XLA_FLAGS", None)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (src, env.get("PYTHONPATH")) if p)
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--scale-worker", json.dumps(cfg)],
-            env=env, capture_output=True, text=True, timeout=1800)
-        line = next((ln for ln in proc.stdout.splitlines()
-                     if ln.startswith(SCALE_TAG)), None)
-        if proc.returncode != 0 or line is None:
-            raise RuntimeError(
-                f"scale worker {cfg} failed (rc={proc.returncode}):\n"
-                f"{proc.stdout}\n{proc.stderr}")
-        records.append(json.loads(line[len(SCALE_TAG):]))
+    run = _scale_subprocess if on_cpu else _scale_worker
     return {
         "smoke": smoke,
         "num_params": grid[0]["N"],
         "strategy": "sync",
-        "sweep": records,
+        "sweep": [run(cfg) for cfg in grid],
     }
+
+
+def _scale_subprocess(cfg: dict) -> dict:
+    """CPU rehearsal of one ``scale`` point in a child process with
+    ``cfg["shards"]`` forced host devices."""
+    src = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    if cfg["shards"] > 1:
+        env["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={cfg['shards']}")
+    else:
+        env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (src, env.get("PYTHONPATH")) if p)
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__),
+         "--scale-worker", json.dumps(cfg)],
+        env=env, capture_output=True, text=True, timeout=1800)
+    line = next((ln for ln in proc.stdout.splitlines()
+                 if ln.startswith(SCALE_TAG)), None)
+    if proc.returncode != 0 or line is None:
+        raise RuntimeError(
+            f"scale worker {cfg} failed (rc={proc.returncode}):\n"
+            f"{proc.stdout}\n{proc.stderr}")
+    return json.loads(line[len(SCALE_TAG):])
 
 
 def main(clients: int = 64, rounds: int = 64, block: int = 16,
@@ -1103,6 +1116,7 @@ if __name__ == "__main__":
                     help="internal: run one bench_scale grid point and "
                          "print SCALE_RESULT:<json>")
     args = ap.parse_args()
+    use_compile_cache()
     if args.scale_worker is not None:
         print(SCALE_TAG + json.dumps(_scale_worker(json.loads(args.scale_worker))))
     else:

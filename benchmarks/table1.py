@@ -27,6 +27,7 @@ from repro.core.operators import all_permutations
 from repro.data.synthetic import make_synth_femnist
 from repro.federated.simulation import FederatedSimulation, FedSimConfig
 from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn_params
+from repro.utils.compile_cache import use_compile_cache
 
 RESULTS = Path(__file__).resolve().parent / "results"
 
@@ -155,4 +156,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

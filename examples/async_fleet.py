@@ -70,6 +70,7 @@ from repro.federated import (
 from repro.federated.selection import POLICIES
 from repro.federated.simulation import FederatedSimulation, FedSimConfig
 from repro.models.mlp import init_mlp_params, mlp_accuracy, mlp_loss
+from repro.utils.compile_cache import use_compile_cache
 
 
 def _config(name: str, args) -> FedSimConfig:
@@ -232,4 +233,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

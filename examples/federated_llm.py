@@ -31,7 +31,7 @@ from repro.launch.sharding_rules import param_shardings
 from repro.models import sharding as msharding
 from repro.models.registry import bundle as make_bundle
 from repro.utils.pytree import tree_count_params
-from repro.utils.sharding import mesh_context
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -80,7 +80,7 @@ def main() -> None:
         }
 
     msharding.configure(True, mesh_axes=mesh.axis_names, manual_axes=("data",))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if args.adjust:
             step_fn = jax.jit(make_federated_adjust_step(mdl, mesh, lr=args.lr))
         else:
@@ -112,4 +112,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

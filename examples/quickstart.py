@@ -12,6 +12,7 @@ from repro.core import AggregationConfig
 from repro.data.synthetic import make_synth_femnist
 from repro.federated.simulation import FederatedSimulation, FedSimConfig
 from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn_params
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -46,4 +47,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

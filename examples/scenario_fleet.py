@@ -50,6 +50,7 @@ from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn_params
 # model-agnostic and XLA CPU's vmapped conv gradient makes the paper CNN
 # orders of magnitude slower per round; pass --cnn for the paper path.
 from repro.models.mlp import init_mlp_params, mlp_accuracy, mlp_loss
+from repro.utils.compile_cache import use_compile_cache
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -197,4 +198,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

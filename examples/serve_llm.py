@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs.registry import ARCHS
 from repro.models.registry import bundle as make_bundle
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -76,4 +77,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -32,7 +32,6 @@ from repro.core.operators import all_permutations, prioritized_score
 from repro.launch.mesh import client_axes, num_clients
 from repro.models.registry import ModelBundle
 from repro.utils.pytree import PyTree, tree_sq_norm
-from repro.utils.sharding import shard_map_compat
 
 CRITERIA_NAMES = ("Ds", "Ld", "Md")
 
@@ -231,13 +230,14 @@ def make_federated_train_step(
                 f"(participation={with_participation}, "
                 f"staleness={with_staleness}), got {len(extra)}"
             )
-        agg, stats = shard_map_compat(
+        agg, stats = jax.shard_map(
             per_client,
-            mesh,
+            mesh=mesh,
             in_specs=(P(), _batch_in_specs(batch, caxes),
                       *(P(caxes) for _ in extra)),
             out_specs=out_specs,
-            manual_axes=caxes,
+            axis_names=set(caxes),
+            check_vma=False,
         )(params, batch, *extra)
         return _sgd(params, agg, lr), stats
 
@@ -280,12 +280,13 @@ def make_federated_adjust_step(
         return tuple(cands), mean_loss
 
     def adjust_step(params, batch, val_batch, prev_quality, priority_idx):
-        cands, mean_loss = shard_map_compat(
+        cands, mean_loss = jax.shard_map(
             per_client,
-            mesh,
+            mesh=mesh,
             in_specs=(P(), _batch_in_specs(batch, caxes)),
             out_specs=(tuple(P() for _ in perms), P()),
-            manual_axes=caxes,
+            axis_names=set(caxes),
+            check_vma=False,
         )(params, batch)
 
         qualities = []

@@ -113,7 +113,7 @@ from repro.kernels import quantize as kquant
 from repro.launch.mesh import client_sharding
 from repro.optim.optimizers import sgd
 from repro.utils.pytree import FlatSpec, PyTree
-from repro.utils.sharding import ShardSpec, shard_map_compat
+from repro.utils.sharding import ShardSpec
 
 
 @dataclass
@@ -1083,10 +1083,11 @@ class FederatedSimulation:
             step = self._build_round_step(shard=shard, label_table=table)
             return jax.lax.scan(step, state, round_ids)
 
-        sharded = shard_map_compat(
-            block, mesh,
+        sharded = jax.shard_map(
+            block, mesh=mesh,
             in_specs=(state_spec, P(), k_spec),
             out_specs=(state_spec, P()),
+            check_vma=False,
         )
 
         def run_block(state: ServerState, round_ids):

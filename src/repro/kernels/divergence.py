@@ -10,10 +10,13 @@ output block is the canonical reduction pattern).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.tiling import block_cols, lane_width
 
 
 def _kernel(g_ref, x_ref, o_ref):
@@ -33,18 +36,21 @@ def _kernel(g_ref, x_ref, o_ref):
 def divergence_sq(
     stacked: jax.Array,
     global_vec: jax.Array,
-    block_n: int = 2048,
+    block_n: Optional[int] = None,
     interpret: bool = True,
 ) -> jax.Array:
     """Per-client squared L2 distance ``[K]`` (f32) to ``global_vec [N]``.
 
     Zero padding is harmless: padded columns contribute ``(0-0)^2``.
-    ``block_n`` is clamped to the lane-aligned width the input needs, so
+    ``block_n`` (``None``: sized from the VMEM budget for this ``K`` and
+    dtype) is clamped to the lane-aligned width the input needs, so
     small vectors are not padded to a full default tile; any ``K >= 1`` /
     ``N >= 1`` works, with f32 accumulation for every storage dtype.
     """
     K, N = stacked.shape
-    block_n = min(block_n, ((N + 127) // 128) * 128)
+    if block_n is None:
+        block_n = block_cols(K, N, stacked.dtype.itemsize, f32_temps=2)
+    block_n = min(block_n, lane_width(N))
     n_pad = (-N) % block_n
     if n_pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, n_pad)))

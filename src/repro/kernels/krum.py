@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import block_cols, lane_width, padded_rows
+
 
 def _gram_kernel(x_ref, o_ref):
     i = pl.program_id(0)
@@ -49,10 +51,9 @@ def _gram_kernel(x_ref, o_ref):
         o_ref[...] += part
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def pairwise_sq_dists(
     stacked: jax.Array,
-    block_n: int = 2048,
     interpret: bool = True,
 ) -> jax.Array:
     """All pairwise squared L2 distances ``[S, S]`` f32 over ``[S, N]``.
@@ -60,10 +61,14 @@ def pairwise_sq_dists(
     Gram-based: zero feature padding contributes zero to every inner
     product, so padding to the lane-aligned block width is harmless.
     The diagonal is clamped to exactly 0 and negatives from float
-    cancellation are floored away.
+    cancellation are floored away.  The tile width comes from the VMEM
+    budget, net of the resident ``[S, S]`` accumulator (two buffers and
+    the partial).
     """
     S, N = stacked.shape
-    block_n = min(block_n, ((N + 127) // 128) * 128)
+    acc_bytes = 3 * padded_rows(S, 4) * lane_width(S) * 4
+    block_n = block_cols(S, N, stacked.dtype.itemsize, f32_temps=1,
+                         fixed_bytes=acc_bytes)
     n_pad = (-N) % block_n
     if n_pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, n_pad)))
@@ -136,13 +141,12 @@ def krum_select(scores: jax.Array, weights: jax.Array, m: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("f", "m", "block_n", "interpret"))
+                   static_argnames=("f", "m", "interpret"))
 def krum_agg(
     stacked: jax.Array,
     weights: jax.Array,
     f: int,
     m: int,
-    block_n: int = 2048,
     interpret: bool = True,
 ):
     """Multi-Krum aggregate ``([N], scores [S])`` over ``[S, N]``.
@@ -151,7 +155,7 @@ def krum_agg(
     plain Krum (the single best-scored client's update), ``m > 1``
     multi-Krum (renormalized weighted mean of the ``m`` best).
     """
-    d2 = pairwise_sq_dists(stacked, block_n=block_n, interpret=interpret)
+    d2 = pairwise_sq_dists(stacked, interpret=interpret)
     scores = krum_scores(d2, weights, f)
     wsel, _ = krum_select(scores, weights, m)
     agg = (wsel @ stacked.astype(jnp.float32)).astype(stacked.dtype)

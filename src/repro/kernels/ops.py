@@ -50,7 +50,6 @@ def flat_weighted_agg(
     stacked: jax.Array,
     weights: jax.Array,
     interpret: Optional[bool] = None,
-    block_n: int = 2048,
 ) -> jax.Array:
     """``w_G[n] = Σ_k p_k · stacked[k, n]`` on the flat representation.
 
@@ -60,8 +59,7 @@ def flat_weighted_agg(
     """
     use_pallas, interp = resolve_kernel_mode(interpret)
     if use_pallas:
-        return weighted_agg(stacked, weights, block_n=block_n,
-                            interpret=interp)
+        return weighted_agg(stacked, weights, interpret=interp)
     out = weights.astype(jnp.float32) @ stacked.astype(jnp.float32)
     return out.astype(stacked.dtype)
 
@@ -92,7 +90,6 @@ def flat_divergence_sq(
     stacked: jax.Array,
     global_vec: jax.Array,
     interpret: Optional[bool] = None,
-    block_n: int = 2048,
 ) -> jax.Array:
     """Per-client squared L2 distance ``[S]`` on the flat representation.
 
@@ -107,8 +104,7 @@ def flat_divergence_sq(
     """
     use_pallas, interp = resolve_kernel_mode(interpret)
     if use_pallas:
-        return divergence_sq(stacked, global_vec, block_n=block_n,
-                             interpret=interp)
+        return divergence_sq(stacked, global_vec, interpret=interp)
     return ref.divergence_ref(stacked, global_vec)
 
 
@@ -117,7 +113,6 @@ def flat_trimmed_agg(
     weights: jax.Array,
     trim: int,
     interpret: Optional[bool] = None,
-    block_n: int = 2048,
 ) -> jax.Array:
     """Coordinate-wise weighted trimmed mean ``[N]`` on the flat path.
 
@@ -129,8 +124,7 @@ def flat_trimmed_agg(
     """
     use_pallas, interp = resolve_kernel_mode(interpret)
     if use_pallas:
-        return trimmed_agg(stacked, weights, trim, block_n=block_n,
-                           interpret=interp)
+        return trimmed_agg(stacked, weights, trim, interpret=interp)
     return ref.trimmed_agg_ref(stacked, weights, trim)
 
 
@@ -164,7 +158,6 @@ def flat_krum_agg(
     f: int,
     m: int,
     interpret: Optional[bool] = None,
-    block_n: int = 2048,
 ):
     """Multi-Krum aggregate ``([N], scores [S])`` on the flat path.
 
@@ -184,7 +177,7 @@ def flat_krum_agg(
     use_pallas, interp = resolve_kernel_mode(interpret)
     if use_pallas:
         return krum_kernel.krum_agg(stacked, weights, f, m,
-                                    block_n=block_n, interpret=interp)
+                                    interpret=interp)
     x = stacked.astype(jnp.float32)
     d2 = krum_kernel.gram_sq_dists(x @ x.T)
     scores = krum_kernel.krum_scores(d2, weights, f)

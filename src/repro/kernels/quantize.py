@@ -2,8 +2,8 @@
 
 The compressed-update streaming layer (``FedSimConfig(compress=...)``):
 each client's flat update is quantized to int8 or int4 with one absmax
-scale per ``block`` contiguous coordinates — the same 2048-lane tile the
-flat server kernels stream (``weighted_agg.block_n``) — so the server
+scale per ``block`` contiguous coordinates — 2048 by default, the widest
+tile the flat server kernels stream (``kernels/tiling.py``) — so the server
 aggregates *storage-dtype* tiles and the scales ride along as an
 ``[S, nb]`` sidecar that is ~0.2% of the payload.
 
@@ -16,16 +16,17 @@ Three layers, mirroring ``weighted_agg.py`` / ``ref.py``:
   compressed runs at rtol 1e-5.
 * :func:`qagg_ref` — the pure-jnp oracle for the fused reduction
   ``out[n] = Σ_k w_k · scale[k, n//block] · q[k, n]`` (f32 accumulation).
-* :func:`qagg` — the Pallas kernel: one ``[K, block]`` int8 tile + its
-  ``[K, 1]`` scale column per grid step, weights resident in VMEM, one
-  f32 ``[block]`` output tile.  Reads a quarter (int8) of the HBM bytes
-  the f32 ``weighted_agg`` pass moves.
+* :func:`qagg` — the Pallas kernel: one ``[K, bn]`` int8 tile (``bn``
+  divides ``block``) + its scale block's ``[K, 1]`` scale column per
+  grid step, weights resident in VMEM, one f32 ``[bn]`` output tile.
+  Reads a quarter (int8) of the HBM bytes the f32 ``weighted_agg`` pass
+  moves.
 
 Wire format: :func:`wire_bytes` accounts one client upload as the packed
 payload (``ceil(N·bits/8)`` value bytes — int4 packs two values per byte,
 see :func:`pack_int4` — plus one f32 scale per block).  The simulation
-keeps int4 values unpacked in int8 storage (XLA int4 support is spotty on
-the pinned jax); the nibble packing is the tested wire format and the
+keeps int4 values unpacked in int8 storage, so one int8 ``qagg`` kernel
+serves both modes; the nibble packing is the tested wire format and the
 byte accounting everywhere reflects it.
 """
 from __future__ import annotations
@@ -37,11 +38,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import LANE, block_cols
+
 #: quantized range per compress mode: values live in [-qmax, qmax]
 QMAX = {"int8": 127, "int4": 7}
 #: wire bits per value per compress mode
 QBITS = {"int8": 8, "int4": 4}
-#: default scale-block size — the flat kernels' streaming tile width
+#: default scale-block size — the flat kernels' widest streaming tile
 QBLOCK = 2048
 
 
@@ -136,7 +139,7 @@ def qagg_ref(
 
 
 def _qagg_kernel(w_ref, s_ref, q_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)          # [K, block]
+    q = q_ref[...].astype(jnp.float32)          # [K, bn]
     w = w_ref[...].astype(jnp.float32)          # [K, 1]
     s = s_ref[...].astype(jnp.float32)          # [K, 1] this block's scales
     o_ref[...] = jnp.sum(q * (w * s), axis=0, keepdims=True)
@@ -152,12 +155,17 @@ def qagg(
 ) -> jax.Array:
     """Fused dequantize-reduce Pallas kernel (see :func:`qagg_ref`).
 
-    One grid step per scale block: streams a ``[K, block]`` int8 tile and
-    its ``[K, 1]`` scale column, multiplies by the resident ``[K, 1]``
-    weights, writes one f32 ``[block]`` output tile.  ``block`` must be
-    the quantizer's scale-block size (the tile *is* the scale
-    granularity).  ``interpret=True`` runs the body in Python on CPU; on
-    TPU pass ``interpret=False``.
+    Streams ``[K, bn]`` int8 tiles, each with its scale block's ``[K, 1]``
+    scale column, multiplies by the resident ``[K, 1]`` weights and
+    writes one f32 ``[bn]`` output tile.  ``block`` must be the
+    quantizer's scale-block size.  The tile width ``bn`` is the widest
+    divisor of ``block`` that fits the VMEM budget for this ``K``
+    (``kernels/tiling.py``) — the whole scale block for small cohorts —
+    so the tiling never changes the scale granularity or the per-column
+    sum.  The scales ride as ``[nb, K, 1]``, so each grid step's block
+    spans the array's full trailing dims, as Mosaic requires.
+    ``interpret=True`` runs the body in Python on CPU; on TPU pass
+    ``interpret=False``.
     """
     K, n = q.shape
     nb = scales.shape[1]
@@ -165,19 +173,24 @@ def qagg(
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad)))
     w2 = weights.reshape(K, 1).astype(jnp.float32)
+    s3 = scales.T.reshape(nb, K, 1)
+    bn = (block_cols(K, block, q.dtype.itemsize, f32_temps=2, divides=block)
+          if block % LANE == 0 else block)
+    per = block // bn                          # tiles per scale block
 
     out = pl.pallas_call(
         _qagg_kernel,
-        grid=(nb,),
+        grid=(nb * per,),
         in_specs=[
-            pl.BlockSpec((K, 1), lambda i: (0, 0)),      # weights, resident
-            pl.BlockSpec((K, 1), lambda i: (0, i)),      # scale column
-            pl.BlockSpec((K, block), lambda i: (0, i)),  # int8 tile
+            pl.BlockSpec((K, 1), lambda i: (0, 0)),              # weights
+            pl.BlockSpec((pl.squeezed, K, 1),
+                         lambda i: (i // per, 0, 0)),            # scales
+            pl.BlockSpec((K, bn), lambda i: (0, i)),             # int8 tile
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, nb * block), jnp.float32),
         interpret=interpret,
-    )(w2, scales, q)
+    )(w2, s3, q)
     return out[0, :n]
 
 

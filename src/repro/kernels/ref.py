@@ -67,6 +67,17 @@ def trimmed_agg_ref(stacked: jax.Array, weights: jax.Array,
     return out.astype(stacked.dtype)
 
 
+def pairwise_sq_dists_ref(stacked: jax.Array) -> jax.Array:
+    """All pairwise squared L2 distances ``[S, S]`` f32 from explicit row
+    differences — no Gram cancellation, no matmul — one row at a time,
+    so the ``[S, S, N]`` difference tensor never materializes.
+
+    ``stacked``: [S, N] (any float dtype) → [S, S] f32, zero diagonal.
+    """
+    x = stacked.astype(jnp.float32)
+    return jax.lax.map(lambda xi: jnp.sum(jnp.square(x - xi), axis=1), x)
+
+
 def krum_agg_ref(stacked: jax.Array, weights: jax.Array, f: int, m: int):
     """Multi-Krum reference: explicit pairwise differences, no Gram trick.
 

@@ -9,6 +9,8 @@ VPU.  Instead the kernel peels extremes: ``trim`` is small (a quarter of
 the cohort at most), so per ``[S, block_n]`` tile it runs ``trim``
 max-peel + min-peel passes that knock one survivor out of the keep-mask
 each — ``O(trim · S · block_n)`` streaming work, no sort, no scatter.
+The peels run as a loop, not unrolled, so a large cohort's trim budget
+does not grow the kernel.
 
 Tie-breaking matches the stable-argsort oracle (``ref.trimmed_agg_ref``)
 exactly: the max peel evicts the *last* duplicate (stable ascending sort
@@ -25,14 +27,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import block_cols
+
 
 def _kernel(x_ref, w_ref, o_ref, *, trim: int):
     x = x_ref[...].astype(jnp.float32)          # [K, bn]
     w = w_ref[...].astype(jnp.float32)          # [K, 1]
     K = x.shape[0]
-    row = jax.lax.broadcasted_iota(jnp.float32, x.shape, 0)
-    keep = jnp.ones_like(x)
-    for _ in range(trim):
+    # Mosaic's iota is integer-only; row indices are exact in f32
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0).astype(jnp.float32)
+
+    def peel(_, keep):
         # peel the current max; last duplicate wins (stable-sort tie rule)
         hi = jnp.max(jnp.where(keep > 0, x, -jnp.inf), axis=0, keepdims=True)
         at_hi = (keep > 0) & (x == hi)
@@ -42,7 +47,9 @@ def _kernel(x_ref, w_ref, o_ref, *, trim: int):
         lo = jnp.min(jnp.where(keep > 0, x, jnp.inf), axis=0, keepdims=True)
         at_lo = (keep > 0) & (x == lo)
         idx = jnp.min(jnp.where(at_lo, row, float(K)), axis=0, keepdims=True)
-        keep = keep * (1.0 - (row == idx).astype(jnp.float32))
+        return keep * (1.0 - (row == idx).astype(jnp.float32))
+
+    keep = jax.lax.fori_loop(0, trim, peel, jnp.ones_like(x))
     wk = w * keep
     num = jnp.sum(x * wk, axis=0, keepdims=True)
     den = jnp.sum(wk, axis=0, keepdims=True)
@@ -51,12 +58,11 @@ def _kernel(x_ref, w_ref, o_ref, *, trim: int):
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("trim", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("trim", "interpret"))
 def trimmed_agg(
     stacked: jax.Array,
     weights: jax.Array,
     trim: int,
-    block_n: int = 2048,
     interpret: bool = True,
 ) -> jax.Array:
     """Coordinate-wise weighted trimmed mean ``[N]`` over ``[S, N]``.
@@ -64,13 +70,13 @@ def trimmed_agg(
     Semantics match :func:`repro.kernels.ref.trimmed_agg_ref` (including
     the zero-surviving-weight fallback to the unweighted kept mean).
     Padded columns are all-zero ties and get sliced away, so zero padding
-    is harmless; ``block_n`` is clamped to the lane-aligned width the
-    input needs.
+    is harmless; the tile width is sized from the VMEM budget for this
+    ``K`` and dtype, clamped to the lane-aligned width the input needs.
     """
     K, N = stacked.shape
     if not 0 <= 2 * trim < K:
         raise ValueError(f"need 0 <= 2*trim < K, got trim={trim} K={K}")
-    block_n = min(block_n, ((N + 127) // 128) * 128)
+    block_n = block_cols(K, N, stacked.dtype.itemsize, f32_temps=6)
     n_pad = (-N) % block_n
     if n_pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, n_pad)))

@@ -11,16 +11,21 @@ bandwidth with f32 accumulation regardless of the storage dtype.
 
 TPU mapping notes:
 * ``block_n`` is a multiple of 128 (lane width); K rides the sublane dim.
+  By default it is sized from a VMEM budget (``kernels/tiling.py``), so
+  the tile stays 2048 lanes for small cohorts and narrows for large K.
 * weights are tiny ([K]) and pinned via a ``(K, 1)`` block that maps to the
   same tile every grid step (compiler keeps it resident).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.tiling import block_cols, lane_width
 
 
 def _kernel(w_ref, x_ref, o_ref):
@@ -33,7 +38,7 @@ def _kernel(w_ref, x_ref, o_ref):
 def weighted_agg(
     stacked: jax.Array,
     weights: jax.Array,
-    block_n: int = 2048,
+    block_n: Optional[int] = None,
     interpret: bool = True,
 ) -> jax.Array:
     """``out[n] = Σ_k weights[k] * stacked[k, n]``.
@@ -42,13 +47,16 @@ def weighted_agg(
     ``interpret=True`` runs the kernel body in Python on CPU (validation
     mode for this container); on TPU pass ``interpret=False``.
 
-    Any ``K >= 1`` / ``N >= 1`` works: ``block_n`` is clamped to the
+    Any ``K >= 1`` / ``N >= 1`` works: ``block_n`` (``None``: sized
+    from the VMEM budget for this ``K`` and dtype) is clamped to the
     lane-aligned width the input actually needs, so a 257-element vector
     pads to 384 columns (one grid step), not 2048.  Accumulation is f32
     regardless of the storage dtype (bf16 in, bf16 out, f32 math).
     """
     K, N = stacked.shape
-    block_n = min(block_n, ((N + 127) // 128) * 128)
+    if block_n is None:
+        block_n = block_cols(K, N, stacked.dtype.itemsize, f32_temps=2)
+    block_n = min(block_n, lane_width(N))
     n_pad = (-N) % block_n
     if n_pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, n_pad)))

@@ -14,19 +14,11 @@ import jax
 from repro.utils.sharding import ShardSpec
 
 
-def _mk_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh``.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer
-    jax; the tier-1 pin (0.4.37) takes neither, so pass them only when
-    available.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def _mk_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (compiler-placed)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,14 +28,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mk_mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1):
-    """Tiny mesh over the real local devices (tests / examples).
+def make_host_mesh(model: int = 1, devices=None):
+    """Tiny mesh over the real local devices (tests / examples), or over
+    the ``devices`` given (a benchmark point on a subset of the host).
 
     Raises a clear ``ValueError`` when ``model`` does not divide the
-    local device count (``model > n`` used to silently produce a
-    ``data = 0`` axis and an opaque mesh error downstream).
+    device count (``model > n`` used to silently produce a ``data = 0``
+    axis and an opaque mesh error downstream).
     """
-    n = len(jax.devices())
+    n = len(jax.devices() if devices is None else devices)
     if model < 1 or model > n or n % model:
         raise ValueError(
             f"make_host_mesh(model={model}): need 1 <= model <= {n} with "
@@ -51,7 +44,7 @@ def make_host_mesh(model: int = 1):
             f"--xla_force_host_platform_device_count=<n> before importing "
             f"jax to widen a CPU host."
         )
-    return _mk_mesh((n // model, model), ("data", "model"))
+    return _mk_mesh((n // model, model), ("data", "model"), devices=devices)
 
 
 def client_axes(mesh) -> tuple:

@@ -15,8 +15,10 @@ Sharding it over a mesh follows one rule:
 
 :class:`ShardSpec` carries the *static* description of the client axes
 (names + sizes) and provides the handful of collectives the engine
-needs.  Its methods are only valid inside a :func:`shard_map_compat`
-body over a mesh containing those axes; with ``num_shards == 1`` they
+needs.  Its methods are only valid inside a ``jax.shard_map`` body over
+a mesh containing those axes (the engine passes ``check_vma=False``:
+the round step is *deterministically* replicated — same PRNG keys on
+every shard — in ways the static checker cannot prove); with ``num_shards == 1`` they
 degrade to (near) no-ops, so the same code path runs on one device.
 """
 from __future__ import annotations
@@ -26,51 +28,6 @@ from dataclasses import dataclass
 from typing import Tuple
 
 import jax
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes=None):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (``check_vma=``); the tier-1 pin
-    (0.4.37) only has ``jax.experimental.shard_map.shard_map``
-    (``check_rep=``).  Replication checking is disabled in both cases:
-    the engine's round step is *deterministically* replicated (same PRNG
-    keys on every shard) in ways the static checker cannot prove.
-
-    ``manual_axes`` restricts manual collectives to a subset of the mesh
-    axes (the Mode-B client axes), leaving the rest — e.g. ``model`` —
-    to the compiler: spelled ``axis_names=`` on new jax, the complement
-    ``auto=`` on the experimental API.
-    """
-    kw = {}
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        try:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False, **kw)
-        except TypeError:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if manual_axes is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False, **kw)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists, else the legacy
-    ``with mesh:`` context (0.4.x) — both make ``mesh`` ambient for
-    jit'd programs whose shardings name its axes."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
 
 
 @dataclass(frozen=True)

@@ -9,11 +9,13 @@
 import jax
 import pytest
 
+from repro.utils.compile_cache import use_compile_cache
+
 jax.config.update("jax_platform_name", "cpu")
 
 # Persistent XLA compilation cache: the fast tier is compile-dominated on
 # CPU, so repeat runs (local iteration, CI re-runs) skip most of it.
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_compilation_cache")
+use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 

@@ -234,7 +234,8 @@ _CHILD = textwrap.dedent("""
 
     import jax
     jax.config.update("jax_platform_name", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_compilation_cache")
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
     from repro.checkpoint import latest_checkpoint, save_pytree

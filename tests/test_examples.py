@@ -162,9 +162,8 @@ class TestLightMains:
         assert capsys.readouterr().out.strip()
 
     def test_federated_llm_runs(self, monkeypatch, capsys):
-        # the Mode-B LM example at toy size — runs on the tier-1 jax pin
-        # through shard_map_compat/mesh_context (utils.sharding), newer
-        # jax through jax.shard_map/jax.set_mesh
+        # the Mode-B LM example at toy size (jax.shard_map under
+        # jax.set_mesh)
         _run_main("federated_llm",
                   ["--steps", "2", "--layers", "1", "--d-model", "32",
                    "--seq", "16", "--batch-per-client", "1"], monkeypatch)

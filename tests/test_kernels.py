@@ -21,7 +21,7 @@ def _tol(dt):
 
 
 @pytest.mark.parametrize("K,N", [(2, 128), (4, 1000), (16, 5000), (37, 257),
-                                 (64, 8192)])
+                                 (64, 8192), (1024, 1000)])
 @pytest.mark.parametrize("dtype", [
     jnp.float32,
     pytest.param(jnp.bfloat16, marks=pytest.mark.slow),  # interpret-mode dup
@@ -46,7 +46,8 @@ def test_weighted_agg_block_sizes(block_n):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("K,N", [(2, 128), (8, 4097), (32, 1024)])
+@pytest.mark.parametrize("K,N", [(2, 128), (8, 4097), (32, 1024),
+                                 (1024, 1000)])
 @pytest.mark.parametrize("dtype", [
     jnp.float32,
     pytest.param(jnp.bfloat16, marks=pytest.mark.slow),  # interpret-mode dup

@@ -375,6 +375,20 @@ class TestKrumKernel:
             self._check(x, w / w.sum(), f, m)
             self._check(x, w / w.sum(), f, 1)      # plain krum
 
+    @pytest.mark.parametrize("S,N", [(5, 40), (37, 1000)])
+    def test_pairwise_dists_match_difference_oracle(self, S, N):
+        """The Gram kernel's distances against explicit row differences
+        (``ref.pairwise_sq_dists_ref``: no Gram cancellation, no matmul)."""
+        from repro.kernels.ref import pairwise_sq_dists_ref
+
+        x = jnp.asarray(np.random.default_rng(S).normal(size=(S, N)),
+                        jnp.float32)
+        want = np.asarray(pairwise_sq_dists_ref(x))
+        assert want.shape == (S, S) and np.all(np.diag(want) == 0)
+        np.testing.assert_allclose(
+            np.asarray(kkrum.pairwise_sq_dists(x, interpret=True)), want,
+            rtol=1e-5, atol=1e-3)
+
     def test_zero_weight_rows_never_selected(self):
         """Dropped clients still serve as *neighbors* (their honest
         vectors inform the distance landscape) but can never be
