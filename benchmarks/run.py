@@ -6,8 +6,6 @@ One section per paper table/figure:
     A + C slice so the harness finishes in CPU-budget time).
   * Figure 1 behaviour — the online-adjustment trace (backtracking events)
     is exercised inside study C and reported as a derived column.
-  * Microbenches — operators, server aggregation, Algorithm-1 candidates
-    (``name,us_per_call,derived`` CSV rows).
 
 Dry-run/roofline numbers are produced by ``python -m repro.launch.dryrun``
 (they need the 512-device XLA override and are therefore not run from
@@ -34,13 +32,6 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default=str(ROOT / "BENCH_roundloop.json"),
                     help="where to write the roundloop results JSON")
     args = ap.parse_args(argv)
-
-    if not args.smoke:
-        print("# === microbenches (name,us_per_call,derived) ===",
-              flush=True)
-        from benchmarks import microbench
-
-        microbench.main()
 
     print("# === round loop: dispatch modes x aggregation strategies ===",
           flush=True)

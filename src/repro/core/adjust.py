@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.core import operators
 from repro.core.aggregate import AggregationConfig, aggregate_models, compute_weights
 from repro.core.operators import Permutation
+from repro.utils import spans
 from repro.utils.pytree import PyTree
 
 # Candidate evaluation: global-model pytree → scalar quality (higher=better).
@@ -101,6 +102,7 @@ def adjust_round(
     )
 
 
+@spans.layer("adjust")
 def adjust_round_vectorized(
     c: jax.Array,
     stacked_models: PyTree,

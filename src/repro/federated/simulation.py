@@ -112,6 +112,7 @@ from repro.kernels import ops as kops
 from repro.kernels import quantize as kquant
 from repro.launch.mesh import client_sharding
 from repro.optim.optimizers import sgd
+from repro.utils import spans
 from repro.utils.pytree import FlatSpec, PyTree
 from repro.utils.sharding import ShardSpec
 
@@ -589,7 +590,7 @@ class FederatedSimulation:
             self._round_step = self._run_one = None
             self._run_block = jax.jit(self._build_run_block_mesh(),
                                       donate_argnums=donate)
-        self._eval_all = jax.jit(self._eval_params)
+        self._eval_all = jax.jit(self._eval_boundary)
 
     # ------------------------------------------------------------------
     def init_state(self) -> ServerState:
@@ -608,6 +609,20 @@ class FederatedSimulation:
                 self.cfg.deadline, jnp.float32))
         return state
 
+    def op_layers(self) -> Tuple[str, Dict[str, str]]:
+        """``(module name, {instruction name: layer})`` of the compiled
+        round block (:mod:`repro.utils.spans`), for attributing the ops of
+        a profiler trace to layers.  Compiles ``_run_block`` for the carry
+        ``run`` passes it; where the persistent compilation cache holds
+        that program, the compile loads it from there (the cache's key
+        leaves metadata out: an entry compiled without the scopes comes
+        back with every op unscoped)."""
+        n = max(1, self.cfg.eval_every)
+        round_ids = jnp.arange(1, n + 1, dtype=jnp.int32)
+        lowered = self._run_block.lower(self.init_state(), round_ids)
+        text = lowered.compile().as_text()
+        return spans.module_name(text), spans.op_layers(text)
+
     # ------------------------------------------------------------------
     def _eval_global(self, params):
         """Per-client test accuracies [K] + size-weighted global accuracy."""
@@ -622,6 +637,12 @@ class FederatedSimulation:
         if self._flat:
             params = self._fspec.unravel(params)
         return self._eval_global(params)
+
+    def _eval_boundary(self, params):
+        """The evaluation that closes a block, under the ``eval`` layer
+        (Algorithm-1's candidate evaluations stay under ``adjust``)."""
+        with spans.layer("eval"):
+            return self._eval_params(params)
 
     def _measure_criteria(
         self, stacked: PyTree, sel: jax.Array, params: PyTree,
@@ -898,39 +919,41 @@ class FederatedSimulation:
                     ef_wave = shard.psum(
                         jnp.where(owned_ef[:, None], rows, 0.0))
                     ef_sel = shard.slice_rows(ef_wave)
-                if colluding_on:
-                    # colluding + compressed: the wave trains honestly
-                    # (flat rows), the collusion pass swaps the crafted
-                    # payloads in, and only then does the wire quantize —
-                    # the attacker corrupts what it uploads, the
-                    # quantizer compresses it like any honest payload
-                    # (same carried = delta + EF ordering as the fused
-                    # per-client path).
-                    wave = local_train(model_params, *train_args)
-                    wave = collude(
-                        wave, params, corrupt_t, atk_keys, corrupt_sel,
-                        shard.psum if shard is not None else None)
-                    carried = (wave - params[None, :]) + ef_sel
-                    q_wave, q_scales = kquant.quantize_blockwise(
-                        carried, compress, qblock)
-                    resid = carried - kquant.dequantize_blockwise(
+                with spans.layer("local_train"):
+                    if colluding_on:
+                        # colluding + compressed: the wave trains honestly
+                        # (flat rows), the collusion pass swaps the crafted
+                        # payloads in, and only then does the wire quantize —
+                        # the attacker corrupts what it uploads, the
+                        # quantizer compresses it like any honest payload
+                        # (same carried = delta + EF ordering as the fused
+                        # per-client path).
+                        wave = local_train(model_params, *train_args)
+                        wave = collude(
+                            wave, params, corrupt_t, atk_keys, corrupt_sel,
+                            shard.psum if shard is not None else None)
+                        carried = (wave - params[None, :]) + ef_sel
+                        q_wave, q_scales = kquant.quantize_blockwise(
+                            carried, compress, qblock)
+                        resid = carried - kquant.dequantize_blockwise(
+                            q_wave, q_scales, qblock)
+                    else:
+                        q_wave, q_scales, resid = local_train(
+                            model_params, params, ef_sel, *train_args)
+                    # the dequantized reconstruction w_G + deq(q) — what the
+                    # server actually "received"; criteria and the nonlinear
+                    # strategies consume this, linear commits use the int8
+                    # wave through the fused kernel instead.
+                    stacked = params[None, :] + kquant.dequantize_blockwise(
                         q_wave, q_scales, qblock)
-                else:
-                    q_wave, q_scales, resid = local_train(
-                        model_params, params, ef_sel, *train_args)
-                # the dequantized reconstruction w_G + deq(q) — what the
-                # server actually "received"; criteria and the nonlinear
-                # strategies consume this, linear commits use the int8
-                # wave through the fused kernel instead.
-                stacked = params[None, :] + kquant.dequantize_blockwise(
-                    q_wave, q_scales, qblock)
             else:
-                stacked = local_train(model_params, *train_args)
-                if colluding_on:
-                    stacked = collude(
-                        stacked, params if flat else model_params,
-                        corrupt_t, atk_keys, corrupt_sel,
-                        shard.psum if shard is not None else None)
+                with spans.layer("local_train"):
+                    stacked = local_train(model_params, *train_args)
+                    if colluding_on:
+                        stacked = collude(
+                            stacked, params if flat else model_params,
+                            corrupt_t, atk_keys, corrupt_sel,
+                            shard.psum if shard is not None else None)
 
             if fleet is not None:
                 mask, contrib = participation(fleet, sel, rnd, k_scen)
@@ -1013,18 +1036,21 @@ class FederatedSimulation:
                     jnp.where(owned[:, None], rows.astype(jnp.float32), 0.0)
                 )
 
-            c = self._measure_criteria(stacked, sel, params, mask,
-                                       last_sync, rnd, label_counts, shard)
+            with spans.layer("criteria"):
+                c = self._measure_criteria(stacked, sel, params, mask,
+                                           last_sync, rnd, label_counts,
+                                           shard)
 
             inp = RoundInputs(rnd=rnd, sel=sel, stacked=stacked, criteria=c,
                               mask=mask, contrib=contrib, dt=dt, shard=shard,
                               quant=((q_wave, q_scales)
                                      if compress is not None else None),
                               qblock=qblock if compress is not None else 0)
-            state, ys = strategy.step(
-                state, inp, cfg.aggregation, cfg.online_adjust,
-                eval_fn=lambda cand: self._eval_params(cand)[1],
-            )
+            with spans.layer("aggregate"):
+                state, ys = strategy.step(
+                    state, inp, cfg.aggregation, cfg.online_adjust,
+                    eval_fn=lambda cand: self._eval_params(cand)[1],
+                )
             ys["participants"] = jnp.sum(mask)
             if deadline_on:
                 # the strategy charged the dead-round unit cost (1.0) for
@@ -1044,7 +1070,7 @@ class FederatedSimulation:
 
         def run_block(state: ServerState, round_ids):
             state, ys = jax.lax.scan(self._round_step, state, round_ids)
-            accs, global_acc = self._eval_params(state.params)
+            accs, global_acc = self._eval_boundary(state.params)
             return state, ys, accs, global_acc
 
         return run_block
@@ -1092,7 +1118,7 @@ class FederatedSimulation:
 
         def run_block(state: ServerState, round_ids):
             state, ys = sharded(state, round_ids, self._label_table)
-            accs, global_acc = self._eval_params(state.params)
+            accs, global_acc = self._eval_boundary(state.params)
             return state, ys, accs, global_acc
 
         return run_block
@@ -1258,98 +1284,117 @@ class FederatedSimulation:
             state = jax.tree.map(lambda x: jnp.array(x, copy=True), state)
 
         while rnd < cfg.max_rounds:
-            n = min(block, cfg.max_rounds - rnd)
-            if self._dp_max_commits is not None:
-                # enforce the budget *before* running: each round commits
-                # at most once, so capping the block at the remaining
-                # affordable commits guarantees the spent epsilon stays
-                # below dp_epsilon — over-budget noised state is never
-                # committed, not rolled back after the fact
-                remaining = self._dp_max_commits - int(state.commits)
-                if remaining <= 0:
+            # host spans on the profiler's clock (no cost without a
+            # profiler): which host step the device waits on at each
+            # block boundary; ``round`` is the block's first round
+            with jax.profiler.StepTraceAnnotation("fedsim.block",
+                                                  step_num=rnd):
+                n = min(block, cfg.max_rounds - rnd)
+                if self._dp_max_commits is not None:
+                    # enforce the budget *before* running: each round
+                    # commits at most once, so capping the block at the
+                    # remaining affordable commits guarantees the spent
+                    # epsilon stays below dp_epsilon — over-budget noised
+                    # state is never committed, not rolled back after the
+                    # fact
+                    with jax.profiler.TraceAnnotation("fedsim.dp_check",
+                                                      round=rnd + 1):
+                        remaining = self._dp_max_commits - int(state.commits)
+                    if remaining <= 0:
+                        budget_exhausted = True
+                        if verbose:
+                            print(
+                                f"[round {rnd:4d}] privacy budget "
+                                f"exhausted: one more commit would spend "
+                                f"past eps={cfg.dp_epsilon} at "
+                                f"delta={cfg.dp_delta} "
+                                f"({int(state.commits)} commits)"
+                            )
+                        break
+                    n = min(n, remaining)
+                blk_arrivals = blk_timeouts = 0.0
+                blk_retries = 0
+                with jax.profiler.TraceAnnotation("fedsim.dispatch",
+                                                  round=rnd + 1):
+                    round_ids = jnp.arange(rnd + 1, rnd + n + 1,
+                                           dtype=jnp.int32)
+                    if cfg.use_scan:
+                        state, ys, accs, global_acc = self._run_block(
+                            state, round_ids)
+                        last = jax.tree.map(lambda a: a[-1], ys)
+                    else:
+                        for rid in round_ids:
+                            state, last = self._run_one(state, rid)
+                            if self._deadline_on:
+                                blk_arrivals += float(last["arrivals"])
+                                blk_timeouts += float(last["timeouts"])
+                                blk_retries += int(last["retried"])
+                        accs, global_acc = self._eval_all(state.params)
+                with jax.profiler.TraceAnnotation("fedsim.pull",
+                                                  round=rnd + 1):
+                    if cfg.use_scan and self._deadline_on:
+                        blk_arrivals = float(jnp.sum(ys["arrivals"]))
+                        blk_timeouts = float(jnp.sum(ys["timeouts"]))
+                        blk_retries = int(jnp.sum(ys["retried"]))
+                    first, rnd = rnd + 1, rnd + n
+                    accs = np.asarray(accs)
+                    frac_above = {t: float(np.mean(accs >= t))
+                                  for t in targets}
+                    for t in targets:
+                        for f in device_fracs:
+                            if (rounds_to[(t, f)] is None
+                                    and frac_above[t] >= f):
+                                rounds_to[(t, f)] = rnd
+                    priority = self._perms[int(last["priority_idx"])]
+                    backtracked = bool(last["backtracked"])
+                    commits = int(state.commits)
+                    epsilon = (self._accountant.epsilon(commits)
+                               if self._accountant is not None else None)
+                    metrics.append(RoundMetrics(
+                        round=rnd, global_acc=float(global_acc),
+                        frac_above=frac_above, priority=priority,
+                        backtracked=backtracked,
+                        num_evaluated=int(last["num_evaluated"]),
+                        weights_entropy=float(last["entropy"]),
+                        participants=int(last["participants"]),
+                        sim_time=float(state.sim_time),
+                        commits=commits,
+                        epsilon_spent=epsilon,
+                        arrivals=blk_arrivals,
+                        timeouts=blk_timeouts,
+                        retries=blk_retries,
+                        deadline=(float(state.deadline) if self._deadline_on
+                                  else 0.0),
+                    ))
+                if next_ckpt is not None and rnd >= next_ckpt:
+                    with jax.profiler.TraceAnnotation("fedsim.checkpoint",
+                                                      round=first):
+                        self._save_checkpoint(rnd, state, metrics, rounds_to)
+                    next_ckpt = ((rnd // ckpt_every) + 1) * ckpt_every
+                if verbose and (rnd % log_every == 0 or rnd >= cfg.max_rounds):
+                    print(
+                        f"[round {rnd:4d}] acc={float(global_acc):.4f} "
+                        f"frac>= {targets[0]:.0%}: "
+                        f"{frac_above[targets[0]]:.2f} "
+                        f"priority={priority} bt={backtracked}"
+                    )
+                # backstop only: the pre-run commit cap above keeps the
+                # spent epsilon strictly below the target, so this cannot
+                # fire for the capped schedules; it guards any future
+                # commit schedule that beats the one-commit-per-round bound
+                if (epsilon is not None and cfg.dp_epsilon is not None
+                        and epsilon >= cfg.dp_epsilon):
                     budget_exhausted = True
                     if verbose:
                         print(
                             f"[round {rnd:4d}] privacy budget exhausted: "
-                            f"one more commit would spend past "
-                            f"eps={cfg.dp_epsilon} at delta={cfg.dp_delta} "
-                            f"({int(state.commits)} commits)"
+                            f"eps={epsilon:.3f} >= {cfg.dp_epsilon} at "
+                            f"delta={cfg.dp_delta} after {commits} commits"
                         )
                     break
-                n = min(n, remaining)
-            round_ids = jnp.arange(rnd + 1, rnd + n + 1, dtype=jnp.int32)
-            blk_arrivals = blk_timeouts = 0.0
-            blk_retries = 0
-            if cfg.use_scan:
-                state, ys, accs, global_acc = self._run_block(state, round_ids)
-                last = jax.tree.map(lambda a: a[-1], ys)
-                if self._deadline_on:
-                    blk_arrivals = float(jnp.sum(ys["arrivals"]))
-                    blk_timeouts = float(jnp.sum(ys["timeouts"]))
-                    blk_retries = int(jnp.sum(ys["retried"]))
-            else:
-                for rid in round_ids:
-                    state, last = self._run_one(state, rid)
-                    if self._deadline_on:
-                        blk_arrivals += float(last["arrivals"])
-                        blk_timeouts += float(last["timeouts"])
-                        blk_retries += int(last["retried"])
-                accs, global_acc = self._eval_all(state.params)
-            rnd += n
-
-            accs = np.asarray(accs)
-            frac_above = {t: float(np.mean(accs >= t)) for t in targets}
-            for t in targets:
-                for f in device_fracs:
-                    if rounds_to[(t, f)] is None and frac_above[t] >= f:
-                        rounds_to[(t, f)] = rnd
-            priority = self._perms[int(last["priority_idx"])]
-            backtracked = bool(last["backtracked"])
-            commits = int(state.commits)
-            epsilon = (self._accountant.epsilon(commits)
-                       if self._accountant is not None else None)
-            metrics.append(RoundMetrics(
-                round=rnd, global_acc=float(global_acc),
-                frac_above=frac_above, priority=priority,
-                backtracked=backtracked,
-                num_evaluated=int(last["num_evaluated"]),
-                weights_entropy=float(last["entropy"]),
-                participants=int(last["participants"]),
-                sim_time=float(state.sim_time),
-                commits=commits,
-                epsilon_spent=epsilon,
-                arrivals=blk_arrivals,
-                timeouts=blk_timeouts,
-                retries=blk_retries,
-                deadline=(float(state.deadline) if self._deadline_on
-                          else 0.0),
-            ))
-            if next_ckpt is not None and rnd >= next_ckpt:
-                self._save_checkpoint(rnd, state, metrics, rounds_to)
-                next_ckpt = ((rnd // ckpt_every) + 1) * ckpt_every
-            if verbose and (rnd % log_every == 0 or rnd >= cfg.max_rounds):
-                print(
-                    f"[round {rnd:4d}] acc={float(global_acc):.4f} "
-                    f"frac>= {targets[0]:.0%}: {frac_above[targets[0]]:.2f} "
-                    f"priority={priority} bt={backtracked}"
-                )
-            # backstop only: the pre-run commit cap above keeps the spent
-            # epsilon strictly below the target, so this cannot fire for
-            # the capped schedules; it guards any future commit schedule
-            # that beats the one-commit-per-round bound
-            if (epsilon is not None and cfg.dp_epsilon is not None
-                    and epsilon >= cfg.dp_epsilon):
-                budget_exhausted = True
-                if verbose:
-                    print(
-                        f"[round {rnd:4d}] privacy budget exhausted: "
-                        f"eps={epsilon:.3f} >= {cfg.dp_epsilon} at "
-                        f"delta={cfg.dp_delta} after {commits} commits"
-                    )
-                break
-            # early stop when the strictest goal is met
-            if all(v is not None for v in rounds_to.values()):
-                break
+                # early stop when the strictest goal is met
+                if all(v is not None for v in rounds_to.values()):
+                    break
 
         self.params = (self._fspec.unravel(state.params) if self._flat
                        else state.params)

@@ -67,5 +67,6 @@ def divergence_sq(
         out_specs=pl.BlockSpec((K, 1), lambda i: (0, 0)),   # resident acc
         out_shape=jax.ShapeDtypeStruct((K, 1), jnp.float32),
         interpret=interpret,
+        name="divergence_sq",
     )(global_vec.reshape(1, padded_n), stacked)
     return out[:, 0]

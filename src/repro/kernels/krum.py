@@ -81,6 +81,7 @@ def pairwise_sq_dists(
         out_specs=pl.BlockSpec((S, S), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((S, S), jnp.float32),
         interpret=interpret,
+        name="pairwise_sq_dists",
     )(stacked)
     sq = jnp.diagonal(gram)
     d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * gram, 0.0)

@@ -190,6 +190,7 @@ def qagg(
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, nb * block), jnp.float32),
         interpret=interpret,
+        name="qagg",
     )(w2, s3, q)
     return out[0, :n]
 

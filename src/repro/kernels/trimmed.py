@@ -92,5 +92,6 @@ def trimmed_agg(
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, padded_n), stacked.dtype),
         interpret=interpret,
+        name="trimmed_agg",
     )(stacked, weights.astype(jnp.float32).reshape(K, 1))
     return out[0, :N]

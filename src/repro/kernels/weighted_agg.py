@@ -73,5 +73,6 @@ def weighted_agg(
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, padded_n), stacked.dtype),
         interpret=interpret,
+        name="weighted_agg",
     )(w2, stacked)
     return out[0, :N]

@@ -83,3 +83,51 @@ def test_kernel_compiles_for_v5e(one_chip, name, S, N):
             for shape, dt in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("needle", ["divergence_sq", "weighted_agg"])
+def test_round_block_kernel_names_for_the_trace(one_chip, needle,
+                                                monkeypatch):
+    """The chipbench rooflines find each kernel in a trace by the HLO name
+    ``<needle>`` or ``<needle>.<n>``: in the round block compiled for a
+    v5e, every instruction so named is the kernel's Mosaic call, under
+    its layer's scope."""
+    import re
+
+    from _helpers import init_mlp_params, mlp_accuracy, mlp_loss
+    from repro.core import AggregationConfig
+    from repro.data.synthetic import make_synth_femnist
+    from repro.federated import FedAvgStrategy
+    from repro.federated.simulation import FederatedSimulation, FedSimConfig
+    from repro.kernels import ops as kops
+    from repro.utils import spans
+
+    monkeypatch.setattr(kops, "resolve_kernel_mode",
+                        lambda interpret=None: (True, False))
+    if needle == "divergence_sq":   # Md streams the divergence kernel
+        kw = dict(online_adjust=True, aggregation=AggregationConfig(
+            criteria=("Md", "Ds", "Ld"), priority=(0, 1, 2)))
+        layer = "criteria"
+    else:                           # FedAvg commits through weighted_agg
+        kw = dict(strategy=FedAvgStrategy())
+        layer = "aggregate"
+    cfg = FedSimConfig(fraction=0.25, batch_size=8, local_epochs=1, lr=0.1,
+                       max_rounds=1, flat_params=True, **kw)
+    sim = FederatedSimulation(
+        make_synth_femnist(num_clients=16, mean_samples=20, seed=3),
+        init_mlp_params(jax.random.key(0), hidden=32), mlp_loss,
+        mlp_accuracy, cfg)
+    carry = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        sim.init_state())
+    ids = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    text = sim._run_block.lower(carry, ids).compile().as_text()
+    named = [line for line in text.splitlines()
+             if re.match(rf"\s*(ROOT\s+)?%{needle}(\.\d+)? = ", line)]
+    assert named
+    assert {re.search(r"custom_call_target=\"(\w+)\"", line).group(1)
+            for line in named if "custom-call(" in line} == {"tpu_custom_call"}
+    assert all("custom-call(" in line for line in named)
+    tab = spans.op_layers(text)
+    assert {tab[spans._INSTR.match(line).group(1)] for line in named} == {
+        layer}
