@@ -274,7 +274,13 @@ class SimResult:
 
 
 class FederatedSimulation:
-    """Server-side driver for the paper's experiments."""
+    """Server-side simulation of the paper's experiments.
+
+    ``acc_fn(params, x, y, mask)`` must be a masked mean of per-row
+    scores, ``sum(score * mask) / max(sum(mask), 1)`` (``cnn_accuracy``
+    and ``mlp_accuracy`` are): evaluation scores each test row on its own
+    and averages the scores per client.  ``eval_rows`` is the number of
+    test rows one evaluation runs, ``sum(test_counts)``."""
 
     def __init__(
         self,
@@ -534,9 +540,16 @@ class FederatedSimulation:
         self.images = jnp.asarray(data.images)
         self.labels = jnp.asarray(data.labels)
         self.counts = jnp.asarray(data.counts)
-        self.t_images = jnp.asarray(data.test_images)
-        self.t_labels = jnp.asarray(data.test_labels)
         self.t_counts = jnp.asarray(data.test_counts)
+        # The test sets packed on the host: each client's first
+        # test_counts[k] rows in client order, and the client owning each
+        # row, so an evaluation runs the real rows and no padding.
+        real = (np.arange(data.test_labels.shape[1])[None, :]
+                < np.asarray(data.test_counts)[:, None])
+        self._t_rows = jnp.asarray(data.test_images[real])
+        self._t_row_labels = jnp.asarray(data.test_labels[real])
+        self._t_owner = jnp.asarray(np.nonzero(real)[0].astype(np.int32))
+        self.eval_rows = int(real.sum())   # rows one evaluation runs
 
         # Static per-client features: the [K, C] label-histogram table is
         # fixed by the dataset, so one exact integer-count table gathered
@@ -549,10 +562,6 @@ class FederatedSimulation:
                          for k in range(data.num_clients)])
         self._label_table = jnp.asarray(
             hist, np.min_scalar_type(int(hist.max(initial=0))))
-
-        max_t = self.t_images.shape[1]
-        self._t_mask = (jnp.arange(max_t)[None, :]
-                        < self.t_counts[:, None]).astype(jnp.float32)
 
         # Fixed per-round shapes -> every jitted program compiles once.
         # Deadline rounds inflate the wave with over-provisioning headroom
@@ -625,11 +634,24 @@ class FederatedSimulation:
 
     # ------------------------------------------------------------------
     def _eval_global(self, params):
-        """Per-client test accuracies [K] + size-weighted global accuracy."""
-        accs = jax.vmap(lambda xi, yi, mi: self.acc_fn(params, xi, yi, mi))(
-            self.t_images, self.t_labels, self._t_mask
-        )
+        """Per-client test accuracies [K] + size-weighted global accuracy.
+
+        Scores every packed test row alone (``vmap`` folds the rows into
+        one batch) and sums the scores per client: for an ``acc_fn`` that
+        is a masked mean of per-row scores this is the masked mean over
+        each client's padded rows, and for 0/1 scores it is that bit for
+        bit."""
+        one = jnp.ones((1,), jnp.float32)
+        scores = jax.vmap(
+            lambda x, y: self.acc_fn(params, x[None], y[None], one)
+        )(self._t_rows, self._t_row_labels)
         w = self.t_counts.astype(jnp.float32)
+        hits = jax.ops.segment_sum(scores, self._t_owner,
+                                   num_segments=w.shape[0],
+                                   indices_are_sorted=True)
+        # the barrier keeps XLA from rewriting a division by the constant
+        # counts as a product with their reciprocals, an ulp off the mean
+        accs = hits / jax.lax.optimization_barrier(jnp.maximum(w, 1.0))
         return accs, jnp.sum(accs * w) / jnp.sum(w)
 
     def _eval_params(self, params):
