@@ -5,11 +5,12 @@
 
 In one process, at the cell's own sizes:
 
-* the program: one ``FederatedSimulation`` built as a run builds it,
-  driven through the checked rounds from each of ``--seeds`` seeded
-  models (the same compiled program and call as a run's, at the
-  configuration's precision), each judged by the float32 reference:
-  these give each number's *lower* reading;
+* the program: one ``FederatedSimulation`` built as a run builds it
+  (one per seed where the model has shared weights), driven through
+  the checked rounds from each of ``--seeds`` seeded models (the same
+  compiled program and call as a run's, at the configuration's
+  precision), each judged by the float32 reference: these give each
+  number's *lower* reading;
 * the control: the reference itself one precision below the
   configuration's (three bfloat16 passes for float32 at ``highest``,
   bfloat16 for float32 at the default), in the program's place, judged
@@ -17,7 +18,7 @@ In one process, at the cell's own sizes:
 * faults, each in the reference put in the program's place: half of
   the cohort left out of the aggregation (the weights renormalised over
   the rest); the reported accuracy altered where it is made (one test
-  image in ten miscounted: 0.1 off); and, where Algorithm-1 is on, its
+  row in ten miscounted: 0.1 off); and, where Algorithm-1 is on, its
   rule picking the worst candidate.  A model left unchanged reads 1 on
   ``change_gap`` by construction and needs no run.
 
@@ -63,20 +64,21 @@ def main() -> None:
 
     def seeded(seed):
         p = model.init_params(config["model"], harness.seed_key(seed))
-        return p, {k: np.asarray(v, np.float32) for k, v in p.items()}
+        return (p, {k: np.asarray(v, np.float32) for k, v in p.items()},
+                harness.init_shared(model, config["model"], seed))
 
     sim = None
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
     for seed in seeds:
-        params0, w0 = seeded(seed)
-        if sim is None:
-            sim = harness.build_sim(cell, data, params0, rec)
+        params0, w0, shared = seeded(seed)
+        if sim is None or shared is not None:   # shared weights are built in
+            sim = harness.build_sim(cell, data, params0, rec, shared)
         sim.params = params0
         t0 = time.perf_counter()
         res = harness.run_program(sim, config)
         observed = harness.observe(res)
         t1 = time.perf_counter()
-        nums = judge.check(observed, w0)
+        nums = judge.check(observed, w0, shared)
         emit("program", seed, nums, {
             "program_s": t1 - t0, "reference_s": time.perf_counter() - t1,
             "acc": observed["acc"], "priority": observed["priority"]})
@@ -84,7 +86,7 @@ def main() -> None:
     del sim
 
     def altered(acc):
-        """An accuracy one test image in ten off."""
+        """An accuracy one test row in ten off."""
         return acc - 0.1 if acc >= 0.1 else acc + 0.1
 
     class HalfCohort(reference.Reference):
@@ -104,15 +106,16 @@ def main() -> None:
     if rec["online_adjust"]:
         systems.append(("fault_worst_pick", WorstPick(data, model, rec)))
     for seed in seeds[:args.control_seeds]:
-        _, w0 = seeded(seed)
+        _, w0, shared = seeded(seed)
         for kind, system in systems:
             t0 = time.perf_counter()
-            emit(kind, seed, judge.check(system.run(w0, rounds), w0),
+            emit(kind, seed, judge.check(system.run(w0, rounds, shared=shared),
+                                         w0, shared),
                  {"s": time.perf_counter() - t0})
         # the float32 reference's own trajectory, its accuracies altered
-        sound = judge.run(w0, rounds)
+        sound = judge.run(w0, rounds, shared=shared)
         emit("fault_answer", seed, judge.check(
-            dict(sound, acc=[altered(a) for a in sound["acc"]]), w0))
+            dict(sound, acc=[altered(a) for a in sound["acc"]]), w0, shared))
 
 
 if __name__ == "__main__":

@@ -10,7 +10,10 @@ configuration's ``dataset.partition``.
 
 A dataset is cached in ``chipbench/.data/`` (git-ignored) as the real
 rows only, keyed by its parameters, and padded on load into the dense
-``[K, max_local, 28, 28]`` layout the program takes.
+``[K, max_local, *row]`` layout the program takes.  A row and its label
+may have any trailing shape and dtype: a ``[28, 28]`` float32 image and
+one int32 class, or ``[T]`` int32 tokens and ``[T]`` int32 next-token
+labels (``-1`` where a position has no target).
 """
 from __future__ import annotations
 
@@ -31,15 +34,17 @@ IMAGE_SHAPE = (28, 28)
 
 @dataclass
 class ClientData:
-    """Dense padded client shards (the program's ``FederatedDataset`` layout)."""
+    """Dense padded client shards (the program's ``FederatedDataset`` layout):
+    client ``k``'s first ``counts[k]`` rows are real, the rest zeros.  The
+    field names are the program's: an ``images`` row may have any shape."""
 
-    images: np.ndarray        # [K, max_n, 28, 28] f32
-    labels: np.ndarray        # [K, max_n] i32
+    images: np.ndarray        # [K, max_n, *row], e.g. [..., 28, 28] f32
+    labels: np.ndarray        # [K, max_n, *label], e.g. [K, max_n] i32
     counts: np.ndarray        # [K] i32
-    test_images: np.ndarray   # [K, max_t, 28, 28] f32
-    test_labels: np.ndarray   # [K, max_t] i32
+    test_images: np.ndarray   # [K, max_t, *row]
+    test_labels: np.ndarray   # [K, max_t, *label]
     test_counts: np.ndarray   # [K] i32
-    num_classes: int
+    num_classes: int          # label values lie in [0, num_classes)
 
     @property
     def num_clients(self) -> int:
@@ -106,10 +111,11 @@ def _partition(name: str):
 
 
 def _pad(rows: np.ndarray, labels: np.ndarray, counts: np.ndarray):
-    """Real rows packed client after client -> dense padded shards."""
+    """Real rows packed client after client -> dense padded shards, rows
+    and labels each keeping their trailing shape and dtype."""
     k, width = len(counts), max(int(counts.max()), 1)
     out = np.zeros((k, width, *rows.shape[1:]), rows.dtype)
-    lab = np.zeros((k, width), np.int32)
+    lab = np.zeros((k, width, *labels.shape[1:]), labels.dtype)
     offs = np.concatenate([[0], np.cumsum(counts)])
     for i in range(k):
         out[i, :counts[i]] = rows[offs[i]:offs[i + 1]]
@@ -122,10 +128,16 @@ def generate(ds: dict) -> dict:
     return _partition(ds["partition"]).make(ds)
 
 
+def cache_path(ds: dict) -> Path:
+    """Where the dataset ``ds`` describes is cached, keyed by its
+    parameters."""
+    key = hashlib.sha256(json.dumps(ds, sort_keys=True).encode()).hexdigest()
+    return CACHE / f"{ds['partition']}-{key[:16]}.npz"
+
+
 def load(ds: dict, cache: bool = True) -> ClientData:
     """The dataset ``ds`` describes, from the cache when it is there."""
-    key = hashlib.sha256(json.dumps(ds, sort_keys=True).encode()).hexdigest()
-    path = CACHE / f"{ds['partition']}-{key[:16]}.npz"
+    path = cache_path(ds)
     packed = None
     if cache and path.is_file():
         with np.load(path) as z:

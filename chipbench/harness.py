@@ -8,16 +8,45 @@ configuration and a traffic mix; each is a data file found by its name:
   fraction, batch, local epochs, learning rate, criteria, Algorithm-1);
 * ``chipbench/limits/<cell>.json``: the limit of each number compared;
 * ``chipbench/models/<model.kind>.py``: the model's initial weights,
-  plain forward pass and operation counts;
+  plain forward pass and operation counts (the contract below);
 * ``chipbench/metrics/<metric>.py``: one reader per per-layer metric.
+
+A model file defines ``init_params(m, key)``, the flat dict of the
+leaves clients train, made on the device in one jitted call from the
+run's seed key; ``forward(params, x, precision)`` and ``loss(params, x,
+y, precision)``, the plain model the reference trains and evaluates;
+``forward_flops(m)``, the operations of one row's forward pass;
+``num_params(m)``; and ``program_fns()``, the system under test's
+``(loss_fn, acc_fn)``.  Optional hooks, each defaulting to what a model
+without it gets:
+
+* ``init_shared(m, key) -> dict``: weights every client holds and no
+  client trains, such as a frozen base.  Default: none.  Its key is the
+  seed key folded with :data:`SHARED_FOLD`.  Where the file defines it,
+  the program (through ``make_sim``) and the reference both get the
+  weights, and ``forward``, ``loss`` and ``row_scores`` take them as a
+  ``shared=`` keyword.  What is compared (``observe``, the change of the
+  weights, the aggregation) reads only ``init_params``' leaves.
+* ``make_sim(fds, params0, shared, sim_cfg)``: builds the
+  ``FederatedSimulation``, so that shared weights can reach the program
+  as an argument rather than as constants compiled into it.  Default:
+  ``FederatedSimulation(fds, params0, *program_fns(), sim_cfg)``.
+* ``row_scores(params, x, y, precision[, shared]) -> [B]``: each test
+  row's score in [0, 1], the per-row score the program's ``acc_fn``
+  averages.  Default: ``argmax(forward(...)) == y``.
+* ``train_flops(m)``: the operations of one training row's forward and
+  backward passes.  Default: ``3 * forward_flops(m)``.
 
 A run builds one ``FederatedSimulation`` (the flat path), drives its
 ``run`` through the first ``checked_rounds`` rounds from the seeded
 model (compiling, or loading from the cache, and warming up), and
 records what they produced.  The same object then runs the measured
 window: one ``run`` of as many rounds as fill ``--seconds`` at the
-warm-up's pace.  After the window the program is freed and the plain
-reference (``chipbench/reference.py``) follows the checked rounds.
+warm-up's pace.  A traced run then reads the op-to-layer table of the
+window's own round block (``chipbench/layers.py``).  After the window
+the program is freed and the plain reference
+(``chipbench/reference.py``) follows the checked rounds, with shared
+weights made anew from the seed.
 """
 from __future__ import annotations
 
@@ -37,6 +66,8 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 #: what never being met keeps ``run`` from stopping early
 NEVER = dict(targets=(2.0,), device_fracs=(1.0,))
+#: folded into the seed key for a model's shared weights
+SHARED_FOLD = 0x5EED
 
 
 class Refused(SystemExit):
@@ -115,6 +146,23 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
 
 
+def init_shared(model, m: dict, seed: int):
+    """The model's shared weights for ``seed``, or ``None`` where its file
+    defines no ``init_shared``."""
+    import jax
+
+    make = getattr(model, "init_shared", None)
+    if make is None:
+        return None
+    return make(m, jax.random.fold_in(seed_key(seed), SHARED_FOLD))
+
+
+def train_flops(model, m: dict) -> int:
+    """Operations of one training row's forward and backward passes."""
+    count = getattr(model, "train_flops", None)
+    return count(m) if count is not None else 3 * model.forward_flops(m)
+
+
 def recipe(config: dict, traffic: dict, counts: np.ndarray) -> dict:
     """The round recipe the reference and the program both follow."""
     k = len(counts)
@@ -184,12 +232,14 @@ def run_program(sim, config: dict):
     return res
 
 
-def build_sim(cell: dict, data, params0: dict, rec: dict):
+def build_sim(cell: dict, data, params0: dict, rec: dict, shared=None):
+    """The cell's ``FederatedSimulation``, built by the model file's
+    ``make_sim`` where it has one."""
     from repro.core import AggregationConfig
     from repro.data.synthetic import FederatedDataset
     from repro.federated import FedSimConfig, FederatedSimulation
 
-    loss_fn, acc_fn = model_module(cell["config"]).program_fns()
+    model = model_module(cell["config"])
     fds = FederatedDataset(
         images=data.images, labels=data.labels, counts=data.counts,
         test_images=data.test_images, test_labels=data.test_labels,
@@ -202,7 +252,9 @@ def build_sim(cell: dict, data, params0: dict, rec: dict):
                                       priority=tuple(rec["priority"])),
         online_adjust=rec["online_adjust"], seed=rec["sim_seed"],
         flat_params=True)
-    return FederatedSimulation(fds, params0, loss_fn, acc_fn, cfg)
+    if hasattr(model, "make_sim"):
+        return model.make_sim(fds, params0, shared, cfg)
+    return FederatedSimulation(fds, params0, *model.program_fns(), cfg)
 
 
 def observe(res) -> dict:
@@ -230,7 +282,7 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
     import jax
 
     from chipbench import data as datasets
-    from chipbench import reference
+    from chipbench import layers, reference
     from chipbench.trace import WINDOW, Trace
 
     counter = CompileCounter()
@@ -241,7 +293,8 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
     rec = recipe(config, cell["traffic"], data.counts)
     params0 = model.init_params(config["model"], seed_key(seed))
     w0 = {k: np.asarray(v, np.float32) for k, v in params0.items()}
-    sim = build_sim(cell, data, params0, rec)
+    sim = build_sim(cell, data, params0, rec,
+                    init_shared(model, config["model"], seed))
 
     # the checked rounds: the window's own call, timed block by block
     stamps = []
@@ -284,7 +337,9 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
     committed = int(res.final_state.commits)
     updates = sum(m.participants for m in res.metrics[:committed])
     attempted = rounds * rec["S"]
-    del res, sim
+    del res
+    op_table = layers.build_table(sim, config) if trace else None
+    del sim
     jax.clear_caches()
     gc.collect()
     log(f"[setup] {setup_s:.3f} s; executables built or loaded "
@@ -295,7 +350,8 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
         f"updates, compilations inside {window_builds}")
 
     t0 = time.perf_counter()
-    nums = reference.Reference(data, model, rec).check(observed, w0)
+    nums = reference.Reference(data, model, rec).check(
+        observed, w0, init_shared(model, config["model"], seed))
     log(f"[reference] {time.perf_counter() - t0:.1f} s over "
         f"{rec['checked_rounds']} rounds; program's accuracy "
         f"{observed['acc']}")
@@ -329,9 +385,11 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
     else:
         tr = Trace.from_dir(str(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        if op_table is not None:
+            layers.log_coverage(tr, *op_table)
         ctx = {"trace": tr, "config": config, "recipe": rec,
-               "rounds": rounds, "model": model,
-               "test_images": int(data.test_counts.sum()),
+               "rounds": rounds, "model": model, "op_layers": op_table,
+               "test_rows": int(data.test_counts.sum()),
                "peaks": peaks_for(dev.device_kind)
                if devices is not None else None}
         for m in cell["per_layer"]:

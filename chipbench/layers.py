@@ -17,10 +17,10 @@ DP budget check (``dp_check``) and the checkpoint write (``checkpoint``).
   averaged over the devices.
 
 The table is read from the program itself (``FederatedSimulation.
-op_layers``): the reader rebuilds the cell's simulation from the run's
-context and compiles its round block, which loads from the persistent
-compilation cache the window's run filled.  A program without that
-method or without the host spans reads nothing here.
+op_layers``): a traced run asks the window's own simulation for it once
+the trace has stopped, and hands it to the readers as
+``ctx["op_layers"]``.  A program without that method or without the
+host spans reads nothing here.
 """
 from __future__ import annotations
 
@@ -45,52 +45,30 @@ def log(msg: str) -> None:
 
 
 # -- the op-to-layer table -------------------------------------------------
-def build_table(ctx) -> Optional[Tuple[str, Dict[str, str]]]:
-    """``(module, {op: layer})`` of the cell's compiled round block, or
-    ``None`` where the program cannot say."""
-    from repro.federated import FederatedSimulation
-
-    if not hasattr(FederatedSimulation, "op_layers"):
+def build_table(sim, config: dict) -> Optional[Tuple[str, Dict[str, str]]]:
+    """``(module, {op: layer})`` of ``sim``'s compiled round block, traced at
+    the configuration's precision as the window's run was, or ``None``
+    where the program cannot say.  A failure is logged, and the run goes
+    on with the readers that need the table reading nothing."""
+    if not hasattr(sim, "op_layers"):
         return None
-    from chipbench import data as datasets
-    from chipbench import harness, precision
+    from chipbench import precision
+    from chipbench.harness import CompileCounter
 
-    config, rec = ctx["config"], ctx["recipe"]
-    data = datasets.load(config["dataset"])
-    # the cohort size is all the round program takes of the fraction
-    cell = {"config": config,
-            "traffic": {"fraction": rec["S"] / len(data.counts)}}
-    params0 = ctx["model"].init_params(config["model"], harness.seed_key(0))
-    sim = harness.build_sim(cell, data, params0, rec)
-    with precision.program(config):
-        return sim.op_layers()
-
-
-def table(ctx) -> Optional[Tuple[str, Dict[str, str]]]:
-    """:func:`build_table`, once per run: the readers share ``ctx``."""
-    if "op_layers" not in ctx:
-        import jax
-
-        misses = []
-
-        def count(event, **kw):
-            if event == "/jax/compilation_cache/cache_misses":
-                misses.append(event)
-
-        jax.monitoring.register_event_listener(count)
-        t0 = time.perf_counter()
-        try:
-            ctx["op_layers"] = build_table(ctx)
-        except Exception:     # a reader reports nothing; the run goes on
-            log("[layers] no op-to-layer table:\n" + traceback.format_exc())
-            ctx["op_layers"] = None
-        finally:
-            jax.monitoring.unregister_event_listener(count)
-        if ctx["op_layers"] is not None:
-            log(f"[layers] table built in {time.perf_counter() - t0:.1f} s, "
-                f"compilation cache misses {len(misses)}")
-            _log_coverage(ctx["trace"], *ctx["op_layers"])
-    return ctx["op_layers"]
+    counter = CompileCounter()
+    t0 = time.perf_counter()
+    try:
+        with precision.program(config):
+            tab = sim.op_layers()
+    except Exception:
+        log("[layers] no op-to-layer table:\n" + traceback.format_exc())
+        return None
+    finally:
+        counter.close()
+    log(f"[layers] table built in {time.perf_counter() - t0:.1f} s; "
+        f"executables built or loaded {counter.builds}, compilation cache "
+        f"misses {counter.misses}")
+    return tab
 
 
 def _in_module(e, module: str) -> bool:
@@ -98,7 +76,7 @@ def _in_module(e, module: str) -> bool:
     return mod is None or mod == module
 
 
-def _log_coverage(tr: Trace, module: str, tab: Dict[str, str]) -> None:
+def log_coverage(tr: Trace, module: str, tab: Dict[str, str]) -> None:
     ops = [e for e in tr.ops() if _in_module(e, module)]
     named = [e for e in ops if e.op in tab]
     tot = sum(e.self_ns for e in ops) or 1.0
@@ -124,7 +102,7 @@ def layer_seconds(tr: Trace, module: str,
 
 
 def device_ms_per_round(ctx) -> Optional[Dict[str, float]]:
-    tab = table(ctx)
+    tab = ctx.get("op_layers")
     if tab is None or not ctx["trace"].devices or ctx["rounds"] <= 0:
         return None
     if "layer_ms" not in ctx:

@@ -12,14 +12,22 @@ and matrix product), and imports nothing of the program:
    scenario) -- the draws the recipe names, made with ``jax.random``;
 2. local SGD: ``steps`` steps of ``w <- w - lr * grad`` of the mean
    cross-entropy, for every client of the cohort;
-3. the criteria (Ds: real rows; Ld: distinct labels; Md:
+3. the criteria (Ds: real rows; Ld: distinct label values over every
+   position of the real rows, ``-1`` being no target; Md:
    ``1 / sqrt(||w_G - w_k|| + 1)``), each normalised over the cohort,
    and the prioritized score (paper Eq. 4) into weights (Eq. 3);
 4. Algorithm-1 (when on): one candidate ``sum_k p_k w_k`` per priority
-   order, each scored by its accuracy on every real test image, and the
+   order, each scored by its accuracy on every real test row, and the
    paper's acceptance rule (keep the current order if it does not
    regress, else the first order that does not, else the best);
-5. the committed model and its accuracy on every real test image.
+5. the committed model and its accuracy on every real test row: the
+   sum of the rows' scores (the model file's ``row_scores``, by default
+   ``argmax == label``) over the number of rows.
+
+Only the leaves of the initial model are trained, aggregated and
+compared.  A model's shared weights (``init_shared``, see
+``chipbench/harness.py``) are an argument of every jitted function here
+and reach ``loss``, ``forward`` and ``row_scores`` as ``shared=``.
 
 Run as the *judge*, it is handed the priority orders a system chose and
 commits those, so that both follow one trajectory; the distance of each
@@ -38,8 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-EVAL_BLOCK = 1024        # test images per forward pass of an evaluation
-TRAIN_IMAGES = 2048      # images per local step over all clients of a chunk
+EVAL_BLOCK = 1024        # test rows per forward pass of an evaluation
+TRAIN_ROWS = 2048        # rows per local step over all clients of a chunk
 
 
 def cohort(sim_seed: int, rnd: int, num_clients: int, s: int,
@@ -65,6 +73,11 @@ def choose(q: np.ndarray, prev_q: float, cur: int) -> int:
     return int(np.argmax(q))
 
 
+def _bound(shared) -> dict:
+    """The keyword a model function takes shared weights by, if any."""
+    return {} if shared is None else {"shared": shared}
+
+
 class Reference:
     """The federated round of one cell, computed plainly.
 
@@ -82,21 +95,25 @@ class Reference:
         self.dtype, self.precision = dtype, precision
         self.perms = list(itertools.permutations(range(len(recipe["criteria"]))))
         d = data
-        # real test rows only, padded to whole evaluation blocks
+        # real test rows only, padded to whole evaluation blocks (rows of
+        # zeros, labels of -1, scored 0 whatever the model says)
         rows = np.concatenate([d.test_images[k, :d.test_counts[k]]
                                for k in range(d.num_clients)])
         labs = np.concatenate([d.test_labels[k, :d.test_counts[k]]
                                for k in range(d.num_clients)])
         self.n_test = len(labs)
-        pad = (-len(labs)) % EVAL_BLOCK
-        self.t_x = jnp.asarray(np.pad(rows, ((0, pad), (0, 0), (0, 0)))
-                               ).reshape(-1, EVAL_BLOCK, *rows.shape[1:])
-        self.t_y = jnp.asarray(np.pad(labs, (0, pad), constant_values=-1)
-                               ).reshape(-1, EVAL_BLOCK)
-        self.distinct = np.array([len(np.unique(d.labels[k, :d.counts[k]]))
+
+        def blocks(a, fill):
+            pad = [(0, (-len(a)) % EVAL_BLOCK)] + [(0, 0)] * (a.ndim - 1)
+            a = np.pad(a, pad, constant_values=fill)
+            return jnp.asarray(a).reshape(-1, EVAL_BLOCK, *a.shape[1:])
+
+        self.t_x, self.t_y = blocks(rows, 0), blocks(labs, -1)
+        self.t_real = blocks(np.ones(self.n_test, bool), False)
+        self.distinct = np.array([self._distinct(d.labels[k, :d.counts[k]])
                                   for k in range(d.num_clients)], np.float32)
         self._train = jax.jit(self._train_cohort)
-        self._evaluate = jax.jit(jax.vmap(self._accuracy))
+        self._evaluate = jax.jit(jax.vmap(self._accuracy, in_axes=(0, None)))
         self._norms = jax.jit(lambda w, g: jnp.sqrt(sum(
             jnp.sum(jnp.square((w[k] - g[k][None]).astype(jnp.float32)),
                     axis=tuple(range(1, w[k].ndim))) for k in w)))
@@ -105,38 +122,53 @@ class Reference:
                        * v.astype(jnp.float32), axis=0).astype(v.dtype)
             for k, v in w.items()})
 
+    @staticmethod
+    def _distinct(labels: np.ndarray) -> int:
+        values = np.unique(labels)
+        return int(np.sum(values >= 0))
+
     # -- local training ------------------------------------------------
-    def _train_cohort(self, params, images, labels, plans):
+    def _train_cohort(self, params, rows, labels, plans, shared):
         lr = jnp.asarray(self.r["lr"], self.dtype)
-        prec = self.precision
+        prec, kw = self.precision, _bound(shared)
 
         def one(xs):
             x, y, plan = xs
 
             def step(w, idx):
-                g = jax.grad(self.model.loss)(w, x[idx], y[idx], prec)
+                g = jax.grad(self.model.loss)(w, x[idx], y[idx], prec, **kw)
                 return {k: w[k] - lr * g[k].astype(self.dtype) for k in w}, None
 
             w, _ = jax.lax.scan(step, params, plan)
             return w
 
-        chunk = max(1, TRAIN_IMAGES // self.r["batch_size"])
-        return jax.lax.map(one, (images, labels, plans), batch_size=chunk)
+        chunk = max(1, TRAIN_ROWS // self.r["batch_size"])
+        return jax.lax.map(one, (rows, labels, plans), batch_size=chunk)
 
     # -- evaluation -----------------------------------------------------
-    def _accuracy(self, params):
-        def block(_, xy):
-            x, y = xy
-            pred = jnp.argmax(self.model.forward(params, x, self.precision),
-                              axis=-1)
-            return None, jnp.sum((pred == y) & (y >= 0))
+    def row_scores(self, params, x, y, shared):
+        """``[B]`` scores in [0, 1]: the model file's ``row_scores``, or
+        whether the row's label is the argmax of the logits."""
+        kw = _bound(shared)
+        if hasattr(self.model, "row_scores"):
+            return self.model.row_scores(params, x, y, self.precision, **kw)
+        logits = self.model.forward(params, x, self.precision, **kw)
+        return jnp.argmax(logits, axis=-1) == y
 
-        _, hits = jax.lax.scan(block, None, (self.t_x, self.t_y))
+    def _accuracy(self, params, shared):
+        def block(_, xyr):
+            x, y, real = xyr
+            scores = self.row_scores(params, x, y, shared)
+            return None, jnp.sum(jnp.where(real, scores, 0).astype(
+                jnp.float32))
+
+        _, hits = jax.lax.scan(block, None, (self.t_x, self.t_y, self.t_real))
         return jnp.sum(hits)
 
-    def accuracy(self, cands: List[dict]) -> np.ndarray:
+    def accuracy(self, cands: List[dict], shared=None) -> np.ndarray:
         stacked = {k: jnp.stack([c[k] for c in cands]) for k in cands[0]}
-        return np.asarray(self._evaluate(stacked)) / self.n_test
+        hits = self._evaluate(stacked, shared)
+        return np.asarray(hits, np.float64) / self.n_test
 
     # -- weights ----------------------------------------------------------
     @staticmethod
@@ -164,8 +196,10 @@ class Reference:
 
     # -- the rounds -------------------------------------------------------
     def run(self, params0: dict, rounds: int,
-            forced: Optional[List[int]] = None) -> Dict[str, object]:
-        """``rounds`` rounds from ``params0``; with ``forced``, commit the
+            forced: Optional[List[int]] = None,
+            shared=None) -> Dict[str, object]:
+        """``rounds`` rounds from ``params0``, under the model's ``shared``
+        weights (``None`` where it has none); with ``forced``, commit the
         given priority orders (as indices into the permutations)."""
         r, d = self.r, self.data
         params = {k: jnp.asarray(v, self.dtype) for k, v in params0.items()}
@@ -176,13 +210,13 @@ class Reference:
             sel, plans = cohort(r["sim_seed"], rnd, d.num_clients, r["S"],
                                 d.counts, r["steps"], r["batch_size"])
             stacked = self._train(params, jnp.asarray(d.images[sel]),
-                                  jnp.asarray(d.labels[sel]), plans)
+                                  jnp.asarray(d.labels[sel]), plans, shared)
             c = self.criteria(sel, stacked, params)
             if r["online_adjust"]:
                 ws = [self.prioritized_weights(c, p) for p in self.perms]
                 cands = [self._aggregate(stacked, jnp.asarray(w, jnp.float32))
                          for w in ws]
-                q = self.accuracy(cands)
+                q = self.accuracy(cands, shared)
                 own = self.rule(q, prev_q, cur)
                 pick = own if forced is None else forced[rnd - 1]
                 out["slack"].append(slack(q, prev_q, cur, pick))
@@ -191,7 +225,7 @@ class Reference:
             else:
                 p = self.prioritized_weights(c, tuple(r["priority"]))
                 params = self._aggregate(stacked, jnp.asarray(p, jnp.float32))
-                acc = float(self.accuracy([params])[0])
+                acc = float(self.accuracy([params], shared)[0])
             out["acc"].append(acc)
             out["priority"].append(cur)
             out["entropy"].append(float(-np.sum(p * np.log(np.maximum(p, 1e-12)))))
@@ -199,27 +233,31 @@ class Reference:
         return out
 
 
-    def check(self, observed: dict, w0: dict) -> Dict[str, float]:
+    def check(self, observed: dict, w0: dict,
+              shared=None) -> Dict[str, float]:
         """Follow the checked rounds forced onto ``observed``'s priority
         orders, evaluate ``observed``'s final model, and compare."""
-        judged = self.run(w0, len(observed["acc"]), forced=observed["priority"])
+        judged = self.run(w0, len(observed["acc"]), forced=observed["priority"],
+                          shared=shared)
         final = {k: jnp.asarray(v, self.dtype)
                  for k, v in observed["params"].items()}
-        evaluated = float(self.accuracy([final])[0])
+        evaluated = float(self.accuracy([final], shared)[0])
         return compare(observed, judged, w0, self.r["online_adjust"],
                        evaluated)
 
 
 def slack(q: np.ndarray, prev_q: float, cur: int, pick: int) -> float:
     """How far the accuracies ``q`` must move for the rule to pick ``pick``
-    (0 where it does)."""
-    if pick == cur:
-        return max(0.0, prev_q - q[cur])
-    keep = max(0.0, q[cur] - prev_q)
+    (0 where it does): as the current order that does not regress, as the
+    first other order that does not, or as the best where every order
+    regresses (the current one included)."""
     others = [j for j in range(len(q)) if j != cur]
+    best = max([q[j] - prev_q for j in others] + [q.max() - q[pick], 0.0])
+    if pick == cur:
+        return float(min(max(0.0, prev_q - q[cur]), best))
+    keep = max(0.0, q[cur] - prev_q)
     first = max([prev_q - q[pick]] + [q[j] - prev_q for j in others
                                       if j < pick] + [0.0])
-    best = max([q[j] - prev_q for j in others] + [q.max() - q[pick], 0.0])
     return float(max(keep, min(first, best)))
 
 
@@ -231,7 +269,7 @@ def leaf_gaps(w0: dict, sys_w: dict, ref_w: dict) -> Dict[str, float]:
     the norm of their difference, both over the larger of the
     reference's change of that leaf and of the median leaf.  Leaves the
     reference moves by under a thousandth of the median leaf are left
-    out (nothing there but rounding).
+    out (nothing there but rounding).  A NaN on either side reads NaN.
     """
     ref_n, gap, diff = {}, {}, {}
     for k in ref_w:
@@ -241,11 +279,17 @@ def leaf_gaps(w0: dict, sys_w: dict, ref_w: dict) -> Dict[str, float]:
         gap[k] = abs(np.linalg.norm(ds) - ref_n[k])
         diff[k] = np.linalg.norm(ds - dr)
     med = float(np.median(list(ref_n.values())))
-    keep = [k for k in ref_n if ref_n[k] >= 1e-3 * med]
+    keep = [k for k in ref_n if not ref_n[k] < 1e-3 * med]
     return {
-        "change_gap": max(gap[k] / max(ref_n[k], med) for k in keep),
-        "change_diff": max(diff[k] / max(ref_n[k], med) for k in keep),
+        "change_gap": worst(gap[k] / np.maximum(ref_n[k], med) for k in keep),
+        "change_diff": worst(diff[k] / np.maximum(ref_n[k], med)
+                             for k in keep),
     }
+
+
+def worst(values) -> float:
+    """The largest of ``values``; NaN where any is."""
+    return float(np.max(list(values)))
 
 
 def compare(observed: dict, judged: dict, w0: dict, online_adjust: bool,
@@ -255,13 +299,13 @@ def compare(observed: dict, judged: dict, w0: dict, online_adjust: bool,
     run forced onto its priority orders, ``evaluated`` the reference's
     accuracy of the system's own final model."""
     nums = {
-        "acc_gap": max(abs(a - b) for a, b in zip(observed["acc"],
-                                                  judged["acc"])),
+        "acc_gap": worst(abs(a - b) for a, b in zip(observed["acc"],
+                                                    judged["acc"])),
         "eval_gap": abs(observed["acc"][-1] - evaluated),
-        "entropy_gap": max(abs(a - b) for a, b in zip(observed["entropy"],
-                                                      judged["entropy"])),
+        "entropy_gap": worst(abs(a - b) for a, b in zip(observed["entropy"],
+                                                        judged["entropy"])),
     }
     if online_adjust:
-        nums["alg1_slack"] = max(judged["slack"])
+        nums["alg1_slack"] = worst(judged["slack"])
     nums.update(leaf_gaps(w0, observed["params"], judged["params"]))
     return {k: float(v) for k, v in nums.items()}

@@ -48,7 +48,8 @@ def main(names) -> None:
         rec = harness.recipe(config, cell["traffic"], data.counts)
         model = harness.model_module(config)
         params = model.init_params(config["model"], jax.random.key(0))
-        sim = harness.build_sim(cell, data, params, rec)
+        sim = harness.build_sim(cell, data, params, rec, harness.init_shared(
+            model, config["model"], 0))
         spec = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=chip), sim.init_state())
         ids = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)

@@ -71,6 +71,6 @@ def test_round_mfu_over_the_window():
                criteria=["Ds"])
     ctx = {"trace": _Trace(0, 0.0, window=2.0), "peaks": PEAKS,
            "model": CNN, "config": MNIST, "recipe": rec,
-           "test_images": 10_000, "rounds": 4}
+           "test_rows": 10_000, "rounds": 4}
     want = 100 * 4 * MFU.round_flops(CNN, MNIST, rec, 10_000) / 2.0 / 197e12
     assert MFU.read(ctx) == pytest.approx(want)
