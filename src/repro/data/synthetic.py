@@ -18,7 +18,7 @@ class, fixed by seed) so the task is learnable but non-trivial.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -34,6 +34,10 @@ class FederatedDataset:
     * ``labels``: ``[num_clients, max_local]`` int32
     * ``counts``: ``[num_clients]`` — true local sizes (rest is padding)
     * ``test_*``: same layout for the per-client local test sets
+
+    A row and its label may have any trailing shape: token rows
+    ``[T]`` with next-token labels ``[T]``, ``-1`` where a position has
+    no target.
     """
 
     images: np.ndarray
@@ -47,11 +51,20 @@ class FederatedDataset:
     def num_clients(self) -> int:
         return self.images.shape[0]
 
-    def label_histogram(self, k: int) -> np.ndarray:
-        h = np.zeros(NUM_CLASSES, np.int64)
-        n = int(self.counts[k])
-        np.add.at(h, self.labels[k, :n], 1)
-        return h
+    @property
+    def num_classes(self) -> int:
+        """Width of a label histogram: one past the largest training label."""
+        return int(self.labels.max(initial=-1)) + 1
+
+    def label_histogram(self, k: int,
+                        num_classes: Optional[int] = None) -> np.ndarray:
+        """``[num_classes]`` counts of client ``k``'s training labels, over
+        every position of its real rows (``num_classes`` defaults to
+        :attr:`num_classes`); a ``-1`` label is no target and is not
+        counted."""
+        width = self.num_classes if num_classes is None else num_classes
+        lab = self.labels[k, :int(self.counts[k])].ravel()
+        return np.bincount(lab[lab >= 0], minlength=width)
 
 
 def _class_templates(rng: np.random.Generator) -> np.ndarray:

@@ -62,10 +62,15 @@ flat path to matvec reduction order (rtol 1e-5, gated in
 full placement table.
 
 The engine is model-agnostic: it takes ``loss_fn(params, x, y)`` and
-``acc_fn(params, x, y, mask)`` plus initial params.
+``acc_fn(params, x, y, mask)`` plus initial params.  A model whose
+clients train only part of it (adapters over a frozen base) hands the
+rest over as ``shared``: an argument of every compiled round block and
+evaluation, never a constant compiled into them, that both functions
+then take as ``shared=``.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, List, Optional, Tuple
@@ -200,6 +205,11 @@ class FedSimConfig:
     make the committed cohort data-dependent, voiding the
     fixed-size-WOR subsampling bound.
 
+    ``eval_block`` evaluates the packed test rows that many at a time
+    (``lax.map``), so that a model whose rows are long sequences never
+    holds every row's activations at once; ``None`` runs every row in
+    one batch.
+
     ``checkpoint_every``/``checkpoint_dir`` write crash-recovery
     checkpoints of the full engine carry (plus run metadata: metrics
     history, targets hit, DP-accountant parameters) at scan-block
@@ -237,6 +247,7 @@ class FedSimConfig:
     deadline_cap: Optional[float] = None   # backoff ceiling (None -> 8x)
     checkpoint_every: Optional[int] = None  # rounds between state snapshots
     checkpoint_dir: Optional[str] = None    # where snapshots land
+    eval_block: Optional[int] = None  # test rows per evaluation pass (None: all)
 
 
 @dataclass
@@ -280,7 +291,12 @@ class FederatedSimulation:
     scores, ``sum(score * mask) / max(sum(mask), 1)`` (``cnn_accuracy``
     and ``mlp_accuracy`` are): evaluation scores each test row on its own
     and averages the scores per client.  ``eval_rows`` is the number of
-    test rows one evaluation runs, ``sum(test_counts)``."""
+    test rows one evaluation runs, ``sum(test_counts)``.
+
+    ``shared`` is a pytree of weights every client holds and none trains
+    (a frozen base under trained adapters).  It is an argument of the
+    compiled round block and evaluation, and ``loss_fn`` and ``acc_fn``
+    get it as ``shared=``; with ``None`` they are called without it."""
 
     def __init__(
         self,
@@ -289,12 +305,21 @@ class FederatedSimulation:
         loss_fn: Callable,
         acc_fn: Callable,
         config: FedSimConfig,
+        shared: Optional[PyTree] = None,
     ):
         self.data = data
         self.cfg = config
         self.loss_fn = loss_fn
         self.acc_fn = acc_fn
         self.params = init_params
+        self.shared = shared
+        # what the round block and the evaluation take after their own
+        # arguments: nothing where the model has no shared weights
+        self._shared_args = () if shared is None else (shared,)
+        if config.eval_block is not None and config.eval_block < 1:
+            raise ValueError(
+                f"FedSimConfig.eval_block must be >= 1, got "
+                f"{config.eval_block}")
         self.strategy: AggregationStrategy = (
             config.strategy if config.strategy is not None else SyncStrategy()
         )
@@ -558,7 +583,9 @@ class FederatedSimulation:
         # count (usually uint8/uint16 — 4-16x smaller than f32 at fleet
         # scale, where this table is the dominant O(K·C) resident) and
         # cast to f32 only on the gathered [S, C] wave slice.
-        hist = np.stack([data.label_histogram(k)
+        # Its width C comes from the data: one past the largest label.
+        width = data.num_classes
+        hist = np.stack([data.label_histogram(k, width)
                          for k in range(data.num_clients)])
         self._label_table = jnp.asarray(
             hist, np.min_scalar_type(int(hist.max(initial=0))))
@@ -628,23 +655,34 @@ class FederatedSimulation:
         back with every op unscoped)."""
         n = max(1, self.cfg.eval_every)
         round_ids = jnp.arange(1, n + 1, dtype=jnp.int32)
-        lowered = self._run_block.lower(self.init_state(), round_ids)
+        lowered = self._run_block.lower(self.init_state(), round_ids,
+                                        *self._shared_args)
         text = lowered.compile().as_text()
         return spans.module_name(text), spans.op_layers(text)
 
     # ------------------------------------------------------------------
-    def _eval_global(self, params):
+    def _eval_global(self, params, shared=None):
         """Per-client test accuracies [K] + size-weighted global accuracy.
 
         Scores every packed test row alone (``vmap`` folds the rows into
-        one batch) and sums the scores per client: for an ``acc_fn`` that
+        one batch, or into batches of ``eval_block`` rows run one after
+        another) and sums the scores per client: for an ``acc_fn`` that
         is a masked mean of per-row scores this is the masked mean over
         each client's padded rows, and for 0/1 scores it is that bit for
         bit."""
         one = jnp.ones((1,), jnp.float32)
-        scores = jax.vmap(
-            lambda x, y: self.acc_fn(params, x[None], y[None], one)
-        )(self._t_rows, self._t_row_labels)
+        kw = {} if shared is None else {"shared": shared}
+
+        def score(xy):
+            x, y = xy
+            return self.acc_fn(params, x[None], y[None], one, **kw)
+
+        rows = (self._t_rows, self._t_row_labels)
+        block = self.cfg.eval_block
+        if block is None or block >= self.eval_rows:
+            scores = jax.vmap(score)(rows)
+        else:
+            scores = jax.lax.map(score, rows, batch_size=block)
         w = self.t_counts.astype(jnp.float32)
         hits = jax.ops.segment_sum(scores, self._t_owner,
                                    num_segments=w.shape[0],
@@ -654,17 +692,19 @@ class FederatedSimulation:
         accs = hits / jax.lax.optimization_barrier(jnp.maximum(w, 1.0))
         return accs, jnp.sum(accs * w) / jnp.sum(w)
 
-    def _eval_params(self, params):
+    def _eval_params(self, params, shared=None):
         """:meth:`_eval_global` accepting either representation."""
         if self._flat:
             params = self._fspec.unravel(params)
-        return self._eval_global(params)
+        if shared is None:
+            return self._eval_global(params)
+        return self._eval_global(params, shared)
 
-    def _eval_boundary(self, params):
+    def _eval_boundary(self, params, shared=None):
         """The evaluation that closes a block, under the ``eval`` layer
         (Algorithm-1's candidate evaluations stay under ``adjust``)."""
         with spans.layer("eval"):
-            return self._eval_params(params)
+            return self._eval_params(params, shared)
 
     def _measure_criteria(
         self, stacked: PyTree, sel: jax.Array, params: PyTree,
@@ -772,17 +812,17 @@ class FederatedSimulation:
             attack_scale = float(fleet.attack_scale)
             colluding_on = is_colluding(attack_name)
         if corrupt_on and not colluding_on:
-            def one_client(global_params, images, labels, plan,
+            def one_client(global_params, shared, images, labels, plan,
                            corrupt_k, attack_key):
-                trained = _one_client_honest(global_params, images, labels,
-                                             plan)
+                trained = _one_client_honest(global_params, shared, images,
+                                             labels, plan)
                 return apply_attack(attack_name, trained, global_params,
                                     corrupt_k, attack_scale, attack_key)
 
-            train_axes = (None, 0, 0, 0, 0, 0)
+            train_axes = (None, None, 0, 0, 0, 0, 0)
         else:
             one_client = None
-            train_axes = (None, 0, 0, 0)
+            train_axes = (None, None, 0, 0, 0)
 
         if colluding_on:
             def collude(wave, gparams, corrupt_loc, keys_loc, corrupt_full,
@@ -805,14 +845,19 @@ class FederatedSimulation:
 
                 return jax.vmap(one)(wave, corrupt_loc, keys_loc)
 
-        def _one_client_honest(global_params, images, labels, plan):
+        def loss_of(params, xb, yb, shared):
+            if shared is None:
+                return loss_fn(params, xb, yb)
+            return loss_fn(params, xb, yb, shared=shared)
+
+        def _one_client_honest(global_params, shared, images, labels, plan):
             opt_state = opt.init(global_params)
 
             def step(carry, idx):
                 params, opt_state = carry
                 xb = jnp.take(images, idx, axis=0)
                 yb = jnp.take(labels, idx, axis=0)
-                grads = jax.grad(loss_fn)(params, xb, yb)
+                grads = jax.grad(loss_of)(params, xb, yb, shared)
                 updates, opt_state = opt.update(grads, opt_state, params)
                 params = jax.tree.map(lambda p, u: p + u, params, updates)
                 return (params, opt_state), None
@@ -867,7 +912,7 @@ class FederatedSimulation:
         else:
             local_train = jax.vmap(one_client, in_axes=train_axes)
 
-        def round_step(state: ServerState, rnd):
+        def round_step(state: ServerState, rnd, shared=None):
             params = state.params
             # the flat carry holds [N]; local SGD needs the model pytree
             model_params = fspec.unravel(params) if flat else params
@@ -903,7 +948,8 @@ class FederatedSimulation:
                 plans_t = shard.slice_rows(plans)
             else:
                 sel_t, plans_t = sel, plans
-            train_args = (self.images[sel_t], self.labels[sel_t], plans_t)
+            train_args = (shared, self.images[sel_t], self.labels[sel_t],
+                          plans_t)
             corrupt_t = atk_keys = corrupt_sel = None
             if corrupt_on:
                 # dedicated stream (fold index 4) so hostile runs perturb
@@ -1071,7 +1117,7 @@ class FederatedSimulation:
             with spans.layer("aggregate"):
                 state, ys = strategy.step(
                     state, inp, cfg.aggregation, cfg.online_adjust,
-                    eval_fn=lambda cand: self._eval_params(cand)[1],
+                    eval_fn=lambda cand: self._eval_params(cand, shared)[1],
                 )
             ys["participants"] = jnp.sum(mask)
             if deadline_on:
@@ -1090,9 +1136,10 @@ class FederatedSimulation:
     def _build_run_block(self):
         """``eval_every`` rounds as one lax.scan + one boundary eval."""
 
-        def run_block(state: ServerState, round_ids):
-            state, ys = jax.lax.scan(self._round_step, state, round_ids)
-            accs, global_acc = self._eval_boundary(state.params)
+        def run_block(state: ServerState, round_ids, shared=None):
+            step = functools.partial(self._round_step, shared=shared)
+            state, ys = jax.lax.scan(step, state, round_ids)
+            accs, global_acc = self._eval_boundary(state.params, shared)
             return state, ys, accs, global_acc
 
         return run_block
@@ -1127,20 +1174,23 @@ class FederatedSimulation:
             deadline=P(),
         )
 
-        def block(state, round_ids, table):
-            step = self._build_round_step(shard=shard, label_table=table)
+        def block(state, round_ids, table, shared):
+            step = functools.partial(
+                self._build_round_step(shard=shard, label_table=table),
+                shared=shared)
             return jax.lax.scan(step, state, round_ids)
 
+        # the shared weights are replicated, like the params
         sharded = jax.shard_map(
             block, mesh=mesh,
-            in_specs=(state_spec, P(), k_spec),
+            in_specs=(state_spec, P(), k_spec, P()),
             out_specs=(state_spec, P()),
             check_vma=False,
         )
 
-        def run_block(state: ServerState, round_ids):
-            state, ys = sharded(state, round_ids, self._label_table)
-            accs, global_acc = self._eval_boundary(state.params)
+        def run_block(state: ServerState, round_ids, shared=None):
+            state, ys = sharded(state, round_ids, self._label_table, shared)
+            accs, global_acc = self._eval_boundary(state.params, shared)
             return state, ys, accs, global_acc
 
         return run_block
@@ -1342,16 +1392,18 @@ class FederatedSimulation:
                                            dtype=jnp.int32)
                     if cfg.use_scan:
                         state, ys, accs, global_acc = self._run_block(
-                            state, round_ids)
+                            state, round_ids, *self._shared_args)
                         last = jax.tree.map(lambda a: a[-1], ys)
                     else:
                         for rid in round_ids:
-                            state, last = self._run_one(state, rid)
+                            state, last = self._run_one(
+                                state, rid, *self._shared_args)
                             if self._deadline_on:
                                 blk_arrivals += float(last["arrivals"])
                                 blk_timeouts += float(last["timeouts"])
                                 blk_retries += int(last["retried"])
-                        accs, global_acc = self._eval_all(state.params)
+                        accs, global_acc = self._eval_all(
+                            state.params, *self._shared_args)
                 with jax.profiler.TraceAnnotation("fedsim.pull",
                                                   round=rnd + 1):
                     if cfg.use_scan and self._deadline_on:

@@ -13,6 +13,7 @@ Pallas flash kernel and the jnp oracle are interchangeable.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -102,6 +103,9 @@ def attention_apply(
     if cfg.qk_norm:
         q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, params["k_norm"], cfg.norm_eps)
+    if cfg.attn_scale is not None:
+        # every attention path below scales scores by 1 / sqrt(hd)
+        q = q * (cfg.attn_scale * math.sqrt(hd))
 
     if angles is not None and cross_kv is None:
         q = apply_rope(q, angles)

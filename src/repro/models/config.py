@@ -59,8 +59,18 @@ class ArchConfig:
     ssm_conv: int = 4
 
     # ---- block layout ----
-    # "attn" | "ssm" | "hybrid" (parallel attn+ssm a la Hymba)
+    # "attn" | "ssm" | "hybrid" (parallel attn+ssm a la Hymba) | "mixed"
+    # (one mixer per layer, named by layer_types: ``models/mixed.py``)
     block_type: str = "attn"
+    layer_types: Optional[Tuple[str, ...]] = None   # "mamba" | "attention"
+    # score scale of attention; None = 1 / sqrt(head_dim)
+    attn_scale: Optional[float] = None
+    # Granite multipliers: embeddings times the first, each mixer's and
+    # MLP's output times the second before its residual add, logits
+    # divided by the third
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # ---- encoder-decoder (whisper) ----
     encoder_layers: int = 0

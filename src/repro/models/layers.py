@@ -21,6 +21,22 @@ def embed_init(rng, vocab: int, dim: int, dtype):
     return (jax.random.normal(rng, (vocab, dim), jnp.float32) * 0.02).astype(dtype)
 
 
+class LoRA:
+    """A frozen projection ``w`` with a low-rank adapter: ``x @ LoRA(w, a,
+    b, scale)`` is ``x @ w + scale * (x @ a) @ b`` (arXiv:2106.09685), so
+    a block that multiplies by ``params[name]`` takes an adapted weight
+    unchanged.  ``a``: ``[in, r]``, ``b``: ``[r, out]``, ``scale``:
+    ``alpha / r``.  Not a pytree: made inside the traced function from
+    the base's and the adapter's leaves."""
+
+    def __init__(self, w: jax.Array, a: jax.Array, b: jax.Array,
+                 scale: float):
+        self.w, self.a, self.b, self.scale = w, a, b, scale
+
+    def __rmatmul__(self, x: jax.Array) -> jax.Array:
+        return x @ self.w + self.scale * ((x @ self.a) @ self.b)
+
+
 def rmsnorm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)

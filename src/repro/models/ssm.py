@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.models.config import ArchConfig
 from repro.models.layers import dense_init, rmsnorm
+from repro.utils import spans
 
 Params = Dict[str, jax.Array]
 
@@ -172,7 +173,8 @@ def ssm_apply(
     Cm = Cf.reshape(B, S, _G, N)
 
     init = state["ssm"] if state is not None else None
-    y, final = ssd_chunked(xh, dt, A, Bm, Cm, cfg.ssm_chunk, init)
+    with spans.part("ssd"):
+        y, final = ssd_chunked(xh, dt, A, Bm, Cm, cfg.ssm_chunk, init)
     y = y + params["D"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B, S, d_in).astype(x.dtype)
 

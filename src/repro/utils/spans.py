@@ -18,16 +18,20 @@ from __future__ import annotations
 
 import contextlib
 import re
-from typing import Dict, Iterator
+from typing import Dict, Iterator, Optional
 
 import jax
 
 PREFIX = "fedsim."
 LAYERS = ("local_train", "criteria", "aggregate", "adjust", "eval")
 UNSCOPED = "unscoped"
+MODEL_PREFIX = "model."
+MODEL_PARTS = ("mamba", "ssd", "attention", "mlp", "logits")
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_PART = re.compile(re.escape(MODEL_PREFIX) + r"(" + "|".join(MODEL_PARTS)
+                   + r")(?![\w.])")
 
 
 @contextlib.contextmanager
@@ -39,6 +43,24 @@ def layer(name: str) -> Iterator[None]:
         yield
 
 
+@contextlib.contextmanager
+def part(name: str) -> Iterator[None]:
+    """Trace the ops built inside under the scope ``model.<name>``."""
+    if name not in MODEL_PARTS:
+        raise ValueError(f"unknown model part {name!r}; have {MODEL_PARTS}")
+    with jax.named_scope(MODEL_PREFIX + name):
+        yield
+
+
+class OpTable(dict):
+    """``{instruction name: layer}``, with ``scopes``: ``{instruction name:
+    model part}`` for the instructions inside a ``model.*`` scope."""
+
+    def __init__(self, layers: Dict[str, str], scopes: Dict[str, str]):
+        super().__init__(layers)
+        self.scopes = scopes
+
+
 def layer_of(op_name: str) -> str:
     """The innermost ``fedsim.*`` layer named in an ``op_name`` path."""
     for part in reversed(op_name.split("/")):
@@ -47,19 +69,30 @@ def layer_of(op_name: str) -> str:
     return UNSCOPED
 
 
-def op_layers(hlo_text: str) -> Dict[str, str]:
+def part_of(op_name: str) -> Optional[str]:
+    """The innermost ``model.*`` part named in an ``op_name``, if any."""
+    found = _PART.findall(op_name)
+    return found[-1] if found else None
+
+
+def op_layers(hlo_text: str) -> OpTable:
     """``{instruction name: layer}`` for every instruction of an HLO
     module's text, nested computations (fusion bodies, loop bodies)
     included; an instruction without ``op_name`` metadata is
-    :data:`UNSCOPED`."""
+    :data:`UNSCOPED`.  Its ``scopes`` name the model part of each
+    instruction that has one (:func:`part_of`)."""
     table: Dict[str, str] = {}
+    scopes: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
         if m is None:
             continue
         name = _OP_NAME.search(line)
         table[m.group(1)] = layer_of(name.group(1)) if name else UNSCOPED
-    return table
+        scope = part_of(name.group(1)) if name else None
+        if scope is not None:
+            scopes[m.group(1)] = scope
+    return OpTable(table, scopes)
 
 
 def module_name(hlo_text: str) -> str:

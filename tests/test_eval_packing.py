@@ -5,7 +5,8 @@
 tests hold it to the padded computation it replaced (``acc_fn`` vmapped
 over the ``[K, max_t]`` test tensor with a mask zeroing the padding):
 per-client and global accuracies bit for bit on the pytree, flat and mesh
-paths, and the same Algorithm-1 choices in a run.
+paths, and the same Algorithm-1 choices in a run; and the rows run a
+block at a time (``eval_block``) bit for bit as in one batch.
 """
 import dataclasses
 
@@ -90,6 +91,26 @@ def test_packed_eval_matches_padded_bitwise(ragged, model, flat):
     want = jax.jit(lambda p: padded_eval(ragged, sim.acc_fn, p))(params)
     _assert_bitwise(got, want)
     assert 0.0 < float(got[1]) < 1.0
+
+
+@pytest.mark.parametrize("block", [1, 3, 7])
+def test_blocked_eval_matches_one_batch_bitwise(ragged, block):
+    sim, params = _trained(ragged, "cnn", _config(flat_params=True))
+    assert sim.eval_rows % 3 and sim.eval_rows % 7    # a remainder block
+    blocked = FederatedSimulation(ragged, params, sim.loss_fn, sim.acc_fn,
+                                  _config(flat_params=True, eval_block=block))
+    x = sim._fspec.ravel(params)
+    _assert_bitwise(jax.jit(blocked._eval_params)(x),
+                    jax.jit(sim._eval_params)(x))
+    text = jax.jit(blocked._eval_params).lower(x).as_text()
+    assert "while" in text       # the blocks run one after another
+
+
+def test_eval_block_must_be_positive(ragged):
+    init, loss_fn, acc_fn = MODELS["mlp"]
+    with pytest.raises(ValueError, match="eval_block"):
+        FederatedSimulation(ragged, init(jax.random.key(0)), loss_fn, acc_fn,
+                            _config(eval_block=0))
 
 
 def test_packed_eval_matches_padded_on_mesh(ragged):

@@ -58,6 +58,32 @@ def test_op_layers_parses_compiled_text():
                    "copy.1": "unscoped", "fusion.9": "eval"}
 
 
+def test_model_part_is_the_innermost_model_scope():
+    assert spans.part_of("jit(f)/model.mamba/model.ssd/mul") == "ssd"
+    # differentiation wraps the scope's name
+    assert spans.part_of(
+        "jit(f)/vmap(transpose(jvp(model.mlp)))/dot_general") == "mlp"
+    assert spans.part_of(
+        "jit(f)/checkpoint/rematted_computation/model.attention/exp") \
+        == "attention"
+    assert spans.part_of("jit(f)/fedsim.eval/model.logits/reduce") \
+        == "logits"
+    assert spans.part_of("jit(f)/model.mlpx/add") is None
+    assert spans.part_of("jit(f)/fedsim.local_train/add") is None
+    with pytest.raises(ValueError):
+        with spans.part("nope"):
+            pass
+
+
+def test_op_table_carries_the_model_parts_apart():
+    text = HLO.replace("fedsim.local_train/tanh",
+                       "fedsim.local_train/model.mamba/model.ssd/tanh")
+    tab = spans.op_layers(text)
+    assert tab == spans.op_layers(HLO)        # the layers are unchanged
+    assert tab.scopes == {"fusion.3": "ssd"}
+    assert spans.op_layers(HLO).scopes == {}
+
+
 @pytest.fixture(scope="module")
 def no_persistent_cache():
     from jax.experimental.compilation_cache import compilation_cache
