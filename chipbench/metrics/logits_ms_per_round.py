@@ -9,4 +9,4 @@ from chipbench import parts
 
 def read(ctx):
     ms = parts.ms_per_round(ctx)
-    return None if ms is None else ms["logits"]
+    return None if ms is None else ms.get("logits")

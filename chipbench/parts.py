@@ -7,8 +7,10 @@ scan), ``model.ssd`` (the chunked scan), ``model.attention``,
 or the scores).  The op table a traced run reads from the window's own
 round block (``chipbench/layers.py``) carries them as ``scopes``,
 ``{op: part}``; each op's self time in the window, averaged over the
-devices, counts to its part, in training and evaluation alike.  A
-program whose table has no ``scopes`` reads nothing here.
+devices, counts to its part, in training and evaluation alike.  The
+parts are whatever names the table carries, so a model with parts of
+its own needs only a reader per part.  A program whose table has no
+``scopes`` reads nothing here, nor does a part the window never ran.
 """
 from __future__ import annotations
 
@@ -16,9 +18,6 @@ from typing import Dict, Optional
 
 from chipbench import layers
 
-# the program's names (``repro.utils.spans``), kept here as well: the
-# benchmark also runs against a program that has no such parts
-PARTS = ("mamba", "ssd", "attention", "mlp", "logits")
 COVERED = ("local_train", "eval")
 
 
@@ -34,18 +33,18 @@ def ms_per_round(ctx) -> Optional[Dict[str, float]]:
     if scopes is None:
         return None
     if "part_ms" not in ctx:
-        out = dict.fromkeys(PARTS, 0.0)
+        out: Dict[str, float] = {}
         covered = total = 0.0
         scale = 1e-6 / len(tr.devices) / ctx["rounds"]
         for e in tr.ops():
             if not layers._in_module(e, module):
                 continue
             part = scopes.get(e.op)
-            if part in out:
-                out[part] += e.self_ns * scale
+            if part is not None:
+                out[part] = out.get(part, 0.0) + e.self_ns * scale
             if table.get(e.op) in COVERED:
                 total += e.self_ns
-                covered += e.self_ns if part in out else 0.0
+                covered += e.self_ns if part is not None else 0.0
         ctx["part_ms"] = out
         layers.log(f"[parts] device ms per round {out}; the parts cover "
                    f"{100.0 * covered / max(total, 1.0):.2f}% of "

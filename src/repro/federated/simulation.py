@@ -122,6 +122,13 @@ from repro.utils.pytree import FlatSpec, PyTree
 from repro.utils.sharding import ShardSpec
 
 
+def _shared_kw(shared) -> dict:
+    """The keyword ``loss_fn``, ``acc_fn`` and ``_eval_global`` take
+    shared weights by: none where the model has none, so such a model
+    (or an override written without them) is called as before."""
+    return {} if shared is None else {"shared": shared}
+
+
 @dataclass
 class FedSimConfig:
     """Simulation hyper-parameters.  Every field is static under jit —
@@ -671,7 +678,7 @@ class FederatedSimulation:
         each client's padded rows, and for 0/1 scores it is that bit for
         bit."""
         one = jnp.ones((1,), jnp.float32)
-        kw = {} if shared is None else {"shared": shared}
+        kw = _shared_kw(shared)
 
         def score(xy):
             x, y = xy
@@ -696,9 +703,7 @@ class FederatedSimulation:
         """:meth:`_eval_global` accepting either representation."""
         if self._flat:
             params = self._fspec.unravel(params)
-        if shared is None:
-            return self._eval_global(params)
-        return self._eval_global(params, shared)
+        return self._eval_global(params, **_shared_kw(shared))
 
     def _eval_boundary(self, params, shared=None):
         """The evaluation that closes a block, under the ``eval`` layer
@@ -846,9 +851,7 @@ class FederatedSimulation:
                 return jax.vmap(one)(wave, corrupt_loc, keys_loc)
 
         def loss_of(params, xb, yb, shared):
-            if shared is None:
-                return loss_fn(params, xb, yb)
-            return loss_fn(params, xb, yb, shared=shared)
+            return loss_fn(params, xb, yb, **_shared_kw(shared))
 
         def _one_client_honest(global_params, shared, images, labels, plan):
             opt_state = opt.init(global_params)

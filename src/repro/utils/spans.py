@@ -26,12 +26,11 @@ PREFIX = "fedsim."
 LAYERS = ("local_train", "criteria", "aggregate", "adjust", "eval")
 UNSCOPED = "unscoped"
 MODEL_PREFIX = "model."
-MODEL_PARTS = ("mamba", "ssd", "attention", "mlp", "logits")
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
-_PART = re.compile(re.escape(MODEL_PREFIX) + r"(" + "|".join(MODEL_PARTS)
-                   + r")(?![\w.])")
+_PART = re.compile(r"(?<![\w.])" + re.escape(MODEL_PREFIX)
+                   + r"([a-z_][a-z0-9_]*)(?![\w.])")
 
 
 @contextlib.contextmanager
@@ -45,9 +44,9 @@ def layer(name: str) -> Iterator[None]:
 
 @contextlib.contextmanager
 def part(name: str) -> Iterator[None]:
-    """Trace the ops built inside under the scope ``model.<name>``."""
-    if name not in MODEL_PARTS:
-        raise ValueError(f"unknown model part {name!r}; have {MODEL_PARTS}")
+    """Trace the ops built inside under the scope ``model.<name>``: a part
+    of a client model (``mamba``, ``ssd``, ``attention``, ...), any
+    lower-case name a model chooses."""
     with jax.named_scope(MODEL_PREFIX + name):
         yield
 

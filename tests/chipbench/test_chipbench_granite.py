@@ -224,12 +224,11 @@ def test_round_block_takes_the_base_as_an_argument():
 
 
 def test_op_table_names_the_model_parts():
-    from repro.utils import spans
-
     sim, _ = _tiny_sim(True)
     module, tab = sim.op_layers()
     assert module == "jit_run_block"
-    assert set(tab.scopes.values()) == set(spans.MODEL_PARTS)
+    assert set(tab.scopes.values()) == {"mamba", "ssd", "attention", "mlp",
+                                        "logits"}
     layers = {tab[op] for op in tab.scopes}
     assert {"local_train", "eval"} <= layers
     assert set(tab.scopes) <= set(tab)
@@ -328,6 +327,25 @@ def test_altered_answer_is_not_correct(tiny, monkeypatch):
     assert gap["value"] > gap["limit"]
 
 
+@pytest.mark.parametrize("fault", ["one_percent_off", "zero_scores"])
+def test_answer_off_at_the_scores_scale_is_not_correct(tiny, monkeypatch,
+                                                       fault):
+    """An answer fault as small as the cell's scores: each row's mean
+    target probability reported 1% off, or as 0."""
+    acc = G.program_accuracy
+
+    def off(*a, **kw):
+        v = acc(*a, **kw)
+        return v * 0.99 if fault == "one_percent_off" else 0.0 * v
+
+    monkeypatch.setattr(G, "program_accuracy", off)
+    res = _run(*tiny)
+    assert not res["correct"]
+    for name in ("acc_gap", "eval_gap"):
+        gap = res["checks"][name]
+        assert gap["value"] > gap["limit"], name
+
+
 PARTS = ("ssd_ms_per_round", "mamba_ms_per_round", "attention_ms_per_round",
          "mlp_ms_per_round", "logits_ms_per_round")
 
@@ -339,8 +357,6 @@ def test_traced_run_reads_every_part(tiny):
     from types import SimpleNamespace as NS
 
     from jax.experimental.compilation_cache import compilation_cache
-
-    from chipbench import parts
 
     cell, data = tiny
     seen = []
@@ -373,5 +389,5 @@ def test_traced_run_reads_every_part(tiny):
                 if tab.get(e.op) == k)) for k in ("local_train", "eval"))}
     covered = sum(e.self_ns for e in ctx["trace"].ops()
                   if tab.get(e.op) in ("local_train", "eval")
-                  and tab.scopes.get(e.op) in parts.PARTS)
+                  and e.op in tab.scopes)
     assert covered >= 0.5 * sum(layer_ms.values())

@@ -58,7 +58,7 @@ def test_op_layers_parses_compiled_text():
                    "copy.1": "unscoped", "fusion.9": "eval"}
 
 
-def test_model_part_is_the_innermost_model_scope():
+def test_model_part_is_the_innermost_model_scope(no_persistent_cache):
     assert spans.part_of("jit(f)/model.mamba/model.ssd/mul") == "ssd"
     # differentiation wraps the scope's name
     assert spans.part_of(
@@ -68,11 +68,18 @@ def test_model_part_is_the_innermost_model_scope():
         == "attention"
     assert spans.part_of("jit(f)/fedsim.eval/model.logits/reduce") \
         == "logits"
-    assert spans.part_of("jit(f)/model.mlpx/add") is None
+    # any part a model names, and only as a whole scope
+    assert spans.part_of("jit(f)/model.experts/model.mlpx/add") == "mlpx"
     assert spans.part_of("jit(f)/fedsim.local_train/add") is None
-    with pytest.raises(ValueError):
-        with spans.part("nope"):
-            pass
+    assert spans.part_of("jit(f)/mymodel.mlp/add") is None
+    assert spans.part_of("jit(f)/model.mlp.w/add") is None
+
+    def f(x):
+        with spans.part("experts"):
+            return jax.numpy.tanh(x) * 2.0
+
+    text = jax.jit(f).lower(jax.numpy.ones(4)).compile().as_text()
+    assert set(spans.op_layers(text).scopes.values()) == {"experts"}
 
 
 def test_op_table_carries_the_model_parts_apart():
