@@ -71,9 +71,16 @@ def ssd_chunked(
     chunk: int,
     init_state: Optional[jax.Array] = None,   # [B, H, P, N]
 ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked SSD scan → (y [B, S, H, P], final_state [B, H, P, N])."""
+    """Chunked SSD scan → (y [B, S, H, P], final_state [B, H, P, N]).
+
+    Head h reads B/C group ``h // (H // G)``. B and C stay at group width
+    throughout: heads are viewed as ``[G, R]`` (R = H // G), so ``C Bᵀ``
+    is formed once per group and the state products contract one shared
+    B or C against all R heads of its group at once.
+    """
     B_, S, H, P = x.shape
-    N = Bm.shape[-1]
+    G, N = Bm.shape[-2:]
+    R = H // G
     pad = (-S) % chunk
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -83,50 +90,49 @@ def ssd_chunked(
     Sp = S + pad
     nc = Sp // chunk
 
-    # chunked views [B, nc, L, ...]
-    xc = x.reshape(B_, nc, chunk, H, P).astype(jnp.float32)
-    dtc = dt.reshape(B_, nc, chunk, H).astype(jnp.float32)
-    Bc = Bm.reshape(B_, nc, chunk, _G, N).astype(jnp.float32)
-    Cc = Cm.reshape(B_, nc, chunk, _G, N).astype(jnp.float32)
-    Bh = jnp.repeat(Bc, H // _G, axis=3)                 # [B, nc, L, H, N]
-    Ch = jnp.repeat(Cc, H // _G, axis=3)
+    # chunked views [B, nc, L, G, ...], heads split as [G, R]
+    xc = x.reshape(B_, nc, chunk, G, R, P).astype(jnp.float32)
+    dtc = dt.reshape(B_, nc, chunk, G, R).astype(jnp.float32)
+    Bc = Bm.reshape(B_, nc, chunk, G, N).astype(jnp.float32)
+    Cc = Cm.reshape(B_, nc, chunk, G, N).astype(jnp.float32)
 
-    dA = dtc * A[None, None, None, :]                    # [B, nc, L, H] (<0)
+    dA = dtc * A.reshape(G, R)                           # [B, nc, L, G, R] (<0)
     dA_cs = jnp.cumsum(dA, axis=2)                       # within-chunk cumsum
     xdt = xc * dtc[..., None]                            # dt-scaled inputs
 
     # ---- intra-chunk (quadratic, MXU) --------------------------------
-    Ldec = jnp.exp(_segsum(jnp.moveaxis(dA, 2, -1)))     # [B, nc, H, L, L]
-    scores = jnp.einsum("bclhn,bcshn->bchls", Ch, Bh)    # [B, nc, H, L, S]
-    y_diag = jnp.einsum("bchls,bchls,bcshp->bclhp",
-                        scores, Ldec, xdt)
+    Ldec = jnp.exp(_segsum(jnp.moveaxis(dA, 2, -1)))     # [B, nc, G, R, L, L]
+    scores = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc)    # [B, nc, G, L, S]
+    y_diag = jnp.einsum("bcgrls,bcsgrp->bclgrp",
+                        scores[:, :, :, None] * Ldec, xdt)
 
     # ---- chunk summary states ----------------------------------------
-    decay_to_end = jnp.exp(dA_cs[:, :, -1:, :] - dA_cs)  # [B, nc, L, H]
-    states = jnp.einsum("bclhn,bclhp->bchpn", Bh, xdt * decay_to_end[..., None])
+    decay_to_end = jnp.exp(dA_cs[:, :, -1:] - dA_cs)     # [B, nc, L, G, R]
+    states = jnp.einsum("bclgn,bclgrp->bcgrpn", Bc, xdt * decay_to_end[..., None])
 
     # ---- inter-chunk recurrence (linear scan) -------------------------
-    chunk_decay = jnp.exp(dA_cs[:, :, -1, :])            # [B, nc, H]
-    s0 = (jnp.zeros((B_, H, P, N), jnp.float32) if init_state is None
-          else init_state.astype(jnp.float32))
+    chunk_decay = jnp.exp(dA_cs[:, :, -1])               # [B, nc, G, R]
+    s0 = (jnp.zeros((B_, G, R, P, N), jnp.float32) if init_state is None
+          else init_state.reshape(B_, G, R, P, N).astype(jnp.float32))
 
     def step(carry, inp):
-        st, dec = inp                                    # [B,H,P,N], [B,H]
+        st, dec = inp                                    # [B,G,R,P,N], [B,G,R]
         prev = carry
-        new = prev * dec[:, :, None, None] + st
+        new = prev * dec[..., None, None] + st
         return new, prev
 
-    decs = jnp.moveaxis(chunk_decay, 1, 0)               # [nc, B, H]
-    sts = jnp.moveaxis(states, 1, 0)                     # [nc, B, H, P, N]
+    decs = jnp.moveaxis(chunk_decay, 1, 0)               # [nc, B, G, R]
+    sts = jnp.moveaxis(states, 1, 0)                     # [nc, B, G, R, P, N]
     final, prevs = jax.lax.scan(step, s0, (sts, decs))
-    prev_states = jnp.moveaxis(prevs, 0, 1)              # [B, nc, H, P, N]
+    prev_states = jnp.moveaxis(prevs, 0, 1)              # [B, nc, G, R, P, N]
 
     # ---- inter-chunk output contribution ------------------------------
     in_decay = jnp.exp(dA_cs)                            # decay from chunk start
-    y_off = jnp.einsum("bclhn,bchpn,bclh->bclhp", Ch, prev_states, in_decay)
+    y_off = (jnp.einsum("bclgn,bcgrpn->bclgrp", Cc, prev_states)
+             * in_decay[..., None])
 
     y = (y_diag + y_off).reshape(B_, Sp, H, P)[:, :S]
-    return y, final
+    return y, final.reshape(B_, H, P, N)
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:

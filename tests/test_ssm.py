@@ -30,25 +30,101 @@ def naive_ssd(x, dt, A, Bm, Cm):
     return y, state
 
 
-@pytest.mark.parametrize("S,chunk", [
-    (16, 4),
-    pytest.param(32, 8, marks=pytest.mark.slow),  # same shape family as 16/4
-    (30, 8),      # ragged tail
-    (64, 64),     # single chunk
-])
-def test_ssd_chunked_matches_naive(S, chunk):
-    rng = np.random.default_rng(0)
-    B_, H, P, N, G = 2, 4, 8, 16, 1
+def _ssd_inputs(rng, B_, S, H, P, N, G):
     x = jnp.asarray(rng.normal(size=(B_, S, H, P)), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.01, 0.2, size=(B_, S, H)), jnp.float32)
     A = -jnp.asarray(rng.uniform(0.5, 2.0, size=(H,)), jnp.float32)
     Bm = jnp.asarray(rng.normal(size=(B_, S, G, N)), jnp.float32)
     Cm = jnp.asarray(rng.normal(size=(B_, S, G, N)), jnp.float32)
+    return x, dt, A, Bm, Cm
+
+
+@pytest.mark.parametrize("S,chunk,G,H", [
+    pytest.param(16, 4, 1, 4, id="16-4"),
+    pytest.param(32, 8, 1, 4, id="32-8",
+                 marks=pytest.mark.slow),  # same shape family as 16/4
+    pytest.param(30, 8, 1, 4, id="30-8"),      # ragged tail
+    pytest.param(64, 64, 1, 4, id="64-64"),    # single chunk
+    pytest.param(30, 8, 2, 8, id="30-8-G2-H8"),  # two B/C groups of 4 heads
+])
+def test_ssd_chunked_matches_naive(S, chunk, G, H):
+    rng = np.random.default_rng(0)
+    x, dt, A, Bm, Cm = _ssd_inputs(rng, 2, S, H, 8, 16, G)
 
     y, final = ssd_chunked(x, dt, A, Bm, Cm, chunk)
     y_ref, final_ref = naive_ssd(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y), y_ref, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(final), final_ref, rtol=1e-4, atol=1e-4)
+
+
+def scan_ssd(x, dt, A, Bm, Cm):
+    """``naive_ssd`` as a differentiable ``lax.scan`` over time steps."""
+    B_, S, H, P = x.shape
+    rep = H // Bm.shape[2]
+    Bh = jnp.repeat(Bm, rep, axis=2)
+    Ch = jnp.repeat(Cm, rep, axis=2)
+
+    def step(state, inp):
+        x_t, dt_t, B_t, C_t = inp                            # [B,H,P], [B,H], [B,H,N] x2
+        dA = jnp.exp(dt_t * A[None])
+        state = state * dA[:, :, None, None] + (
+            dt_t[:, :, None] * x_t)[..., None] * B_t[:, :, None, :]
+        return state, jnp.einsum("bhpn,bhn->bhp", state, C_t)
+
+    s0 = jnp.zeros((B_, H, P, Bm.shape[-1]), jnp.float32)
+    final, ys = jax.lax.scan(
+        step, s0, tuple(jnp.moveaxis(a, 1, 0) for a in (x, dt, Bh, Ch)))
+    return jnp.moveaxis(ys, 0, 1), final
+
+
+@pytest.mark.parametrize("G,H", [(1, 4), (2, 4)])
+def test_ssd_chunked_grad_matches_scan(G, H):
+    """The backward pass of the chunked SSD against a sequential scan's."""
+    rng = np.random.default_rng(3)
+    x, dt, A, Bm, Cm = _ssd_inputs(rng, 2, 14, H, 4, 8, G)
+    wy = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    wf = jnp.asarray(rng.normal(size=(2, H, 4, 8)), jnp.float32)
+
+    def scalar(fn):
+        def f(x, dt, Bm, Cm):
+            y, final = fn(x, dt, A, Bm, Cm)
+            return jnp.sum(y * wy) + jnp.sum(final * wf)
+        return jax.grad(f, argnums=(0, 1, 2, 3))
+
+    with jax.default_matmul_precision("highest"):
+        got = scalar(lambda *a: ssd_chunked(*a, chunk=4))(x, dt, Bm, Cm)
+        want = scalar(scan_ssd)(x, dt, Bm, Cm)
+    for name, g, w in zip(("x", "dt", "B", "C"), got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def _intermediate_shapes(jaxpr):
+    """Every equation output's shape in ``jaxpr`` and the jaxprs it nests."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _intermediate_shapes(sub)
+
+
+def test_ssd_chunked_keeps_bc_at_group_width():
+    """No value of the SSD or its gradient repeats B or C to the heads."""
+    H, P, N = 8, 4, 16
+    x, dt, A, Bm, Cm = _ssd_inputs(np.random.default_rng(4), 1, 12, H, P, N, 1)
+
+    def loss(x, dt, Bm, Cm):
+        y, final = ssd_chunked(x, dt, A, Bm, Cm, 6)
+        return jnp.sum(y) + jnp.sum(final)
+
+    for fn in (lambda *a: ssd_chunked(a[0], a[1], A, a[2], a[3], 6),
+               jax.grad(loss, argnums=(0, 1, 2, 3))):
+        shapes = list(_intermediate_shapes(jax.make_jaxpr(fn)(x, dt, Bm, Cm).jaxpr))
+        assert shapes
+        assert not [s for s in shapes if s[-2:] == (H, N)]
 
 
 def test_ssd_init_state_continuation():
