@@ -7,9 +7,8 @@ One section per paper table/figure:
   * Figure 1 behaviour — the online-adjustment trace (backtracking events)
     is exercised inside study C and reported as a derived column.
 
-Dry-run/roofline numbers are produced by ``python -m repro.launch.dryrun``
-(they need the 512-device XLA override and are therefore not run from
-here); see EXPERIMENTS.md §Dry-run / §Roofline.
+Chip numbers (time, rooflines, per-layer breakdown) come from
+``chipbench/``, not from here.
 """
 from __future__ import annotations
 

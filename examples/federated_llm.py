@@ -1,12 +1,15 @@
-"""Device-aware federated training of a transformer LM (Mode B).
+"""Device-aware federated training of a transformer LM.
 
-Demonstrates the *scaling layer*: the paper's criteria-weighted aggregation
-driving a modern LM on a device mesh, exactly the computation the dry-run
-lowers for the production pod — here on the host's devices.
+A reduced qwen2-style LM trains through the same round engine as the
+paper's CNN (``FederatedSimulation`` on its flat server path): each client
+owns a topic slice of a synthetic corpus and trains next-token prediction
+locally, and the server weights the updates by the paper's prioritized
+criteria (Md > Ds > Ld).  ``--adjust`` adds Algorithm-1 online priority
+adjustment; ``--fedavg`` runs the dataset-size-only FedAvg baseline.
+Accuracy is next-token accuracy on each client's held-out documents.
 
-The default model is a reduced qwen2-style LM; ``--layers/--d-model`` scale
-it up (``--d-model 768 --layers 12`` ≈ 100M params — a few hundred steps of
-that is a real overnight CPU run; the default finishes in minutes).
+The default model finishes in minutes on a CPU; ``--layers/--d-model``
+scale it up (``--d-model 768 --layers 12`` is about 100M parameters).
 
     PYTHONPATH=src python examples/federated_llm.py --steps 30
     PYTHONPATH=src python examples/federated_llm.py --adjust --steps 30
@@ -21,22 +24,37 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ARCHS
-from repro.data.synthetic import make_lm_federated
-from repro.federated.distributed import (
-    make_federated_adjust_step,
-    make_federated_train_step,
-)
-from repro.launch.mesh import make_host_mesh, num_clients
-from repro.launch.sharding_rules import param_shardings
-from repro.models import sharding as msharding
+from repro.core import AggregationConfig
+from repro.data.synthetic import FederatedDataset, make_lm_federated
+from repro.federated import FedAvgStrategy, FederatedSimulation, FedSimConfig
 from repro.models.registry import bundle as make_bundle
-from repro.utils.pytree import tree_count_params
+from repro.models.transformer import lm_logits
 from repro.utils.compile_cache import use_compile_cache
+from repro.utils.pytree import tree_count_params
+
+NUM_CLIENTS = 8
+FRACTION = 0.5         # 4 clients train each round
+DOCS_PER_CLIENT = 16
+TEST_DOCS = 4          # the last documents of each client are its test set
+
+
+def lm_dataset(tokens: np.ndarray) -> FederatedDataset:
+    """Next-token rows from ``[K, docs, T + 1]`` token documents: rows are
+    ``tokens[..., :-1]`` and labels ``tokens[..., 1:]``; each client's
+    last ``TEST_DOCS`` documents are held out as its test set."""
+    k, docs, _ = tokens.shape
+    train, test = tokens[:, :docs - TEST_DOCS], tokens[:, docs - TEST_DOCS:]
+    return FederatedDataset(
+        images=train[..., :-1], labels=train[..., 1:],
+        counts=np.full(k, train.shape[1], np.int32),
+        test_images=test[..., :-1], test_labels=test[..., 1:],
+        test_counts=np.full(k, TEST_DOCS, np.int32),
+    )
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--steps", type=int, default=30)
+    ap.add_argument("--steps", type=int, default=30, help="rounds")
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--seq", type=int, default=128)
@@ -48,10 +66,6 @@ def main() -> None:
                     help="FedAvg baseline instead of prioritized MCA")
     args = ap.parse_args()
 
-    mesh = make_host_mesh(model=1)
-    K = num_clients(mesh)
-    print(f"[fed-llm] mesh {dict(mesh.shape)} -> {K} federated clients")
-
     cfg = ARCHS["qwen2-0.5b"].reduced().with_overrides(
         num_layers=args.layers,
         d_model=args.d_model,
@@ -62,53 +76,43 @@ def main() -> None:
     )
     mdl = make_bundle(cfg)
     params = mdl.init(jax.random.key(0))
-    print(f"[fed-llm] params: {tree_count_params(params) / 1e6:.1f}M")
-    params = jax.device_put(params, param_shardings(params, mesh))
+    print(f"[fed-llm] {NUM_CLIENTS} clients, "
+          f"params: {tree_count_params(params) / 1e6:.1f}M")
 
     # non-IID client corpora: each client owns a topic slice of the vocab
-    toks, _ = make_lm_federated(K, cfg.vocab_size, args.seq + 1,
-                                docs_per_client=64, seed=1)
-    rng = np.random.default_rng(2)
+    toks, _ = make_lm_federated(NUM_CLIENTS, cfg.vocab_size, args.seq + 1,
+                                docs_per_client=DOCS_PER_CLIENT, seed=1)
+    data = lm_dataset(toks)
 
-    def sample_batch(step):
-        docs = rng.integers(0, toks.shape[1], size=(K, args.batch_per_client))
-        seqs = np.stack([toks[k, docs[k]] for k in range(K)])  # [K, b, S+1]
-        seqs = seqs.reshape(K * args.batch_per_client, args.seq + 1)
-        return {
-            "tokens": jnp.asarray(seqs[:, :-1]),
-            "labels": jnp.asarray(seqs[:, 1:]),
-        }
+    def loss_fn(p, x, y):
+        return mdl.loss(p, {"tokens": x, "labels": y})[0]
 
-    msharding.configure(True, mesh_axes=mesh.axis_names, manual_axes=("data",))
-    with jax.set_mesh(mesh):
-        if args.adjust:
-            step_fn = jax.jit(make_federated_adjust_step(mdl, mesh, lr=args.lr))
-        else:
-            step_fn = jax.jit(make_federated_train_step(
-                mdl, mesh, lr=args.lr, priority=(2, 0, 1),
-                fedavg_baseline=args.fedavg,
-            ))
+    def acc_fn(p, x, y, mask):
+        # a masked mean of per-row next-token hit rates
+        hits = jnp.argmax(lm_logits(p, cfg, x), axis=-1) == y
+        w = mask.astype(jnp.float32)
+        return (jnp.sum(jnp.mean(hits, axis=-1) * w)
+                / jnp.maximum(jnp.sum(w), 1.0))
 
-        prev_q = jnp.asarray(-1e9, jnp.float32)
-        prio = jnp.asarray(0, jnp.int32)
-        t0 = time.time()
-        for step in range(args.steps):
-            batch = sample_batch(step)
-            if args.adjust:
-                val = {k: v[: 2] for k, v in batch.items()}
-                params, stats = step_fn(params, batch, val, prev_q, prio)
-                prev_q, prio = stats["quality"], stats["priority_idx"]
-                extra = (f" perm={int(prio)} "
-                         f"bt={bool(stats['backtracked'])}")
-            else:
-                params, stats = step_fn(params, batch)
-                w = np.asarray(stats["weight"])
-                extra = f" weights=[{w.min():.3f}..{w.max():.3f}]"
-            if step % 5 == 0 or step == args.steps - 1:
-                print(f"[fed-llm] step {step:4d} loss={float(stats['loss']):.4f}"
-                      f"{extra} ({time.time() - t0:.0f}s)", flush=True)
-    msharding.configure(False)
-    print("[fed-llm] done — loss should be falling from ~ln(2048)=7.6")
+    sim_cfg = FedSimConfig(
+        fraction=FRACTION, batch_size=args.batch_per_client,
+        local_epochs=1, lr=args.lr, max_rounds=args.steps,
+        aggregation=AggregationConfig(priority=(2, 0, 1)),  # Md > Ds > Ld
+        online_adjust=args.adjust,
+        strategy=FedAvgStrategy() if args.fedavg else None,
+        flat_params=True,
+    )
+    sim = FederatedSimulation(data, params, loss_fn, acc_fn, sim_cfg)
+    t0 = time.time()
+    result = sim.run(targets=(1.0,), device_fracs=(1.0,), verbose=False)
+    for m in result.metrics:
+        if m.round % 5 == 1 or m.round == args.steps:
+            print(f"[fed-llm] round {m.round:4d} "
+                  f"next-token acc={m.global_acc:.4f} "
+                  f"priority={m.priority} bt={m.backtracked}")
+    print(f"[fed-llm] done in {time.time() - t0:.0f}s — next-token accuracy "
+          f"{result.metrics[0].global_acc:.4f} -> "
+          f"{result.metrics[-1].global_acc:.4f}")
 
 
 if __name__ == "__main__":

@@ -14,7 +14,7 @@ CONFIG = ArchConfig(
     qkv_bias=True,
     rope_theta=1_000_000.0,
     tie_embeddings=True,
-    # beyond-paper serving variant: ring-buffer window for long_500k
+    # serving variant: ring-buffer decode cache window
     long_context_window=8_192,
     source="arXiv:2407.10671 (Qwen2 technical report)",
 )

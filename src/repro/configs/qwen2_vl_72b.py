@@ -1,7 +1,7 @@
 """qwen2-vl-72b — VLM backbone with M-RoPE [arXiv:2409.12191].
 
-Vision encoder (ViT) is a STUB per the assignment carve-out: input_specs()
-provides patch embeddings + 3-D (t/h/w) M-RoPE position ids.
+Vision encoder (ViT) is a STUB: the model takes patch embeddings and
+3-D (t/h/w) M-RoPE position ids.
 """
 from repro.models.config import ArchConfig
 

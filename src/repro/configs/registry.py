@@ -1,4 +1,4 @@
-"""Arch-id → config lookup (``--arch <id>`` in every launcher)."""
+"""Arch-id → config lookup."""
 from repro.configs.gemma3_27b import CONFIG as gemma3_27b
 from repro.configs.granite_20b import CONFIG as granite_20b
 from repro.configs.hymba_1_5b import CONFIG as hymba_1_5b

@@ -1,9 +1,7 @@
 """whisper-small — encoder-decoder audio backbone [arXiv:2212.04356].
 
-Conv/mel frontend is a STUB per the assignment carve-out: input_specs()
-provides 1500 precomputed frame embeddings. long_500k is skipped for this
-arch (DESIGN.md §4): a 524k decode context has no semantics for a 448-token
-speech decoder.
+Conv/mel frontend is a STUB: the model takes precomputed frame
+embeddings.
 """
 from repro.models.config import ArchConfig
 

@@ -14,8 +14,8 @@ Two implementations:
   *lazy*, exactly like the paper's `while` loop).
 * :func:`adjust_round_vectorized` — evaluates every permutation in one
   lowered computation (vmap over the m! candidate aggregates) and applies
-  the same acceptance rule with `jnp.where`.  This is what the distributed
-  runtime uses: a single XLA program per round, no host round-trips.
+  the same acceptance rule with `jnp.where`.  This is what the round
+  engine uses: a single XLA program per round, no host round-trips.
 """
 from __future__ import annotations
 

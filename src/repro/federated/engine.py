@@ -1,10 +1,10 @@
 """Round-engine core: one server loop, pluggable aggregation strategies.
 
-PR 1 left the repo with two bespoke round drivers — the single-host
-simulation and the Mode-B distributed step — each hand-rolling criteria
-measurement, weighting and Algorithm-1 state.  This module extracts the
-shared server-side machinery so sync, buffered-async and FedAvg-baseline
-execution are *policies* over one engine rather than three copies of it:
+The server-side machinery of the round engine
+(``federated/simulation.py``): criteria weighting, the commit and the
+Algorithm-1 state live here once, so sync, buffered-async and
+FedAvg-baseline execution are *policies* over one engine rather than
+three copies of it:
 
 * :class:`ServerState` — the scan carry: global params, Algorithm-1
   quality/priority, per-client ``last_sync`` staleness clocks, the async

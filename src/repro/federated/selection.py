@@ -38,7 +38,7 @@ traced arrays.
 
 Adding a policy: subclass :class:`SelectionPolicy`, implement
 ``select``, register it in :data:`POLICIES` — the engine, the benchmark
-sweep (``benchmarks/roundloop.py``) and the Mode-B helper
+sweep (``benchmarks/roundloop.py``) and the ``[K]`` mask helper
 (:func:`round_participation`) pick it up by name.
 
 Corruption blindness (hostile-fleet contract): policies may read the
@@ -333,11 +333,11 @@ def round_participation(
 ) -> jax.Array:
     """Run ``policy`` and scatter its pick to a ``[K]`` 0/1 mask.
 
-    The Mode-B distributed step keeps *every* mesh client resident and
-    gates non-participants with the ``participation`` argument of
-    ``make_federated_train_step(with_participation=True)`` — this helper
-    is the bridge: the same policies that drive the single-host engine
-    produce that gate.  Pure jnp, jit-safe.
+    For a caller that keeps *every* client resident and gates the
+    non-participants with a ``[K]`` mask: the same policies that drive
+    the round engine produce that mask, from the same context.  Nothing
+    in the round engine calls it; its tests pin it to the engine's
+    policies.  Pure jnp, jit-safe.
     """
     if last_sync is None:
         last_sync = jnp.zeros((num_clients,), jnp.int32)

@@ -154,7 +154,7 @@ class FedSimConfig:
     copying it per call.
 
     ``mesh`` (a ``jax.sharding.Mesh``, e.g. from
-    ``launch.mesh.make_host_mesh`` / ``make_production_mesh``) runs the
+    ``launch.mesh.make_host_mesh``) runs the
     round block sharded over the mesh's client axes — requires
     ``flat_params=True`` and ``use_scan=True``, and both the fleet size
     ``K`` and the round size ``S`` must be divisible by the product of

@@ -1,7 +1,7 @@
 """Collective-finishing variants of the flat server kernels.
 
-The cross-shard generalization of the Mode-B pattern in
-``federated/distributed.py``: every shard holds its own wave block
+The cross-shard form of the flat server kernels in :mod:`ops`, for
+the round block under a mesh: every shard holds its own wave block
 ``stacked_loc = stacked[i*S_loc:(i+1)*S_loc]`` of the round's ``[S, N]``
 flat client matrix, runs the *same* fused kernel from :mod:`ops` on its
 block, and a single ``psum`` / ``all_gather`` / ``all_to_all`` over the

@@ -1,7 +1,7 @@
-"""Production mesh construction (assignment spec).
+"""Host mesh construction.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state; ``dryrun.py`` sets ``XLA_FLAGS`` *before* calling these.
+state: a caller can set ``XLA_FLAGS`` before the first call.
 
 The federated client axes of a mesh (everything except ``model``) are
 what :mod:`repro.federated.simulation` shards the flat server path
@@ -12,20 +12,6 @@ from __future__ import annotations
 import jax
 
 from repro.utils.sharding import ShardSpec
-
-
-def _mk_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` with every axis ``Auto`` (compiler-placed)."""
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-                         devices=devices)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 (256 chips/pod) single-pod, or 2x16x16 (512 chips) multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, devices=None):
@@ -44,19 +30,15 @@ def make_host_mesh(model: int = 1, devices=None):
             f"--xla_force_host_platform_device_count=<n> before importing "
             f"jax to widen a CPU host."
         )
-    return _mk_mesh((n // model, model), ("data", "model"), devices=devices)
+    # every axis Auto: the compiler places what the program leaves open
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=devices)
 
 
 def client_axes(mesh) -> tuple:
     """The federated client axes of a mesh (everything except 'model')."""
     return tuple(a for a in mesh.axis_names if a != "model")
-
-
-def num_clients(mesh) -> int:
-    n = 1
-    for a in client_axes(mesh):
-        n *= mesh.shape[a]
-    return n
 
 
 def client_sharding(mesh) -> ShardSpec:

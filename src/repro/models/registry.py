@@ -1,8 +1,8 @@
 """Uniform model API over decoder-only and encoder-decoder backbones.
 
 ``bundle(cfg)`` returns a :class:`ModelBundle` with a single calling
-convention used by the federated runtime, the launcher, the dry-run and the
-smoke tests — independent of architecture family.
+convention used by the LM example and the smoke tests — independent of
+architecture family.
 """
 from __future__ import annotations
 

@@ -5,8 +5,7 @@ or moved symbol shows up here, not in a user's terminal), and the
 registry-driven fleet examples must *run* end to end at toy sizes: they
 enumerate ``PRESETS`` / ``STRATEGIES`` instead of hard-coded lists, so a
 preset or strategy added to a registry is exercised by these tests
-automatically.  The heavier mains (paper-scale FEMNIST, the LLM pair)
-run under ``-m slow`` only.
+automatically.  The heavier FEMNIST main runs under ``-m slow`` only.
 """
 import importlib
 import json
@@ -161,12 +160,16 @@ class TestLightMains:
         _run_main("quickstart", [], monkeypatch)
         assert capsys.readouterr().out.strip()
 
-    def test_federated_llm_runs(self, monkeypatch, capsys):
-        # the Mode-B LM example at toy size (jax.shard_map under
-        # jax.set_mesh)
+    @pytest.mark.parametrize("flags", [[], ["--fedavg"], ["--adjust"]],
+                             ids=["prioritized", "fedavg", "adjust"])
+    def test_federated_llm_runs(self, flags, monkeypatch, capsys):
+        # the LM example at toy size through FederatedSimulation's flat
+        # path: prioritized criteria, the FedAvg baseline, and
+        # Algorithm-1's candidate sweep
         _run_main("federated_llm",
-                  ["--steps", "2", "--layers", "1", "--d-model", "32",
-                   "--seq", "16", "--batch-per-client", "1"], monkeypatch)
+                  flags + ["--steps", "2", "--layers", "1", "--d-model", "32",
+                           "--seq", "16", "--batch-per-client", "1"],
+                  monkeypatch)
         assert "done" in capsys.readouterr().out
 
 
@@ -176,11 +179,3 @@ class TestHeavyMains:
         _run_main("femnist_federated",
                   ["--clients", "8", "--rounds", "2", "--hidden", "16",
                    "--out", str(tmp_path / "femnist")], monkeypatch)
-
-    def test_federated_llm_adjust_runs(self, monkeypatch):
-        # Algorithm-1 online adjustment on the LM: the m!-candidate
-        # sweep is the heavy variant of the fast smoke above
-        _run_main("federated_llm",
-                  ["--adjust", "--steps", "2", "--layers", "1",
-                   "--d-model", "32", "--seq", "16",
-                   "--batch-per-client", "1"], monkeypatch)

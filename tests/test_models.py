@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import ARCHS
-from repro.configs.shapes import SHAPES
 from repro.models.registry import bundle
 from repro.models.transformer import lm_logits
 from repro.utils.pytree import tree_count_params
@@ -157,11 +156,3 @@ def test_paper_cnn_param_count():
     # eval_shape: count parameters without materializing the 6.6M floats
     params = jax.eval_shape(init_cnn_params, jax.random.key(0))
     assert tree_count_params(params) == 6_603_710  # paper §3, exact
-
-
-def test_whisper_long500k_skip_reason():
-    from repro.launch.specs import skip_reason
-
-    assert skip_reason(ARCHS["whisper-small"], SHAPES["long_500k"])
-    assert skip_reason(ARCHS["whisper-small"], SHAPES["decode_32k"]) is None
-    assert skip_reason(ARCHS["mamba2-2.7b"], SHAPES["long_500k"]) is None

@@ -265,7 +265,7 @@ class TestOraclePolicy:
 
 
 # ---------------------------------------------------------------------------
-# factory + Mode-B participation bridge
+# factory + [K] participation mask
 # ---------------------------------------------------------------------------
 
 class TestFactoryAndBridge:
